@@ -56,7 +56,7 @@ def rhs_kernel_entry(quick: bool = True) -> dict:
     """Arithmetic-intensity entry for the fused DGSEM-RHS mega-kernel.
 
     Compiles the pure-jnp reference RHS and reads XLA's own cost analysis
-    (flops, bytes accessed) through the `cost_analysis_dict` shim, then
+    (flops, bytes accessed), then
     contrasts the unfused arithmetic intensity with the fused ideal — the
     mega-kernel touches HBM only for the state in, cs field in and RHS out
     (every intermediate lives in VMEM), so its AI is flops over that
@@ -67,7 +67,6 @@ def rhs_kernel_entry(quick: bool = True) -> dict:
 
     from repro.cfd import initial, solver
     from repro.cfd.solver import HITConfig
-    from repro.launch.hlo_analysis import cost_analysis_dict
 
     cases = [("hit_reduced", HITConfig(n_poly=3, n_elem=2,
                                        use_kernels=False))]
@@ -84,7 +83,7 @@ def rhs_kernel_entry(quick: bool = True) -> dict:
         compiled = jax.jit(
             lambda u, cs: solver.navier_stokes_rhs(u, cs, cfg, ops_d)
         ).lower(u, cs).compile()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         flops = float(cost.get("flops", 0.0))
         bytes_unfused = float(cost.get("bytes accessed", 0.0))
         # fused ideal: read state + cs, write rhs — intermediates in VMEM

@@ -261,7 +261,7 @@ def lint_source(path: str, src: str, *, hot: bool | None = None,
                            for e in HOT_EXCLUDES))
     if kernel_module is None:
         kernel_module = ("src/repro/kernels/" in rel
-                         and not rel.endswith(("policy.py", "_compat.py")))
+                         and not rel.endswith("policy.py"))
     tree = ast.parse(src, filename=path)
     lint = _FileLint(path, tree, hot=hot, kernel_module=kernel_module,
                      registry_names=(_registry_names()

@@ -31,7 +31,7 @@ import re
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 from jax._src import source_info_util
 
 from .entrypoints import ENTRYPOINTS, Built, EntryPoint

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 import threading
 import time
@@ -48,6 +49,46 @@ class RunnerConfig:
     keep_checkpoints: int = 3
     seed: int = 0
     async_checkpoint: bool = True
+
+
+def read_metrics(path: str) -> list[dict]:
+    """All records of a jsonl metrics stream ([] if it does not exist)."""
+    if not os.path.exists(path):
+        return []
+    with open(path) as f:
+        return [json.loads(line) for line in f if line.strip()]
+
+
+def run_faults(records: list[dict], expected: int,
+               return_keys: tuple[str, ...] = ("return_norm",)) -> list[str]:
+    """Why a training run's metric records do not show a clean run.
+
+    The loops recover from faults on their own — `Runner.train` retries a
+    failed iteration and both loops keep the old params when an update is
+    non-finite — so a run can finish while its numbers are not those of a
+    clean run.  Entry points that must not pass silently (`rl_train`,
+    `chip_smoke.py`) fail on any retry, any skipped or reverted update, a
+    non-finite return, or fewer than `expected` iteration records (those
+    carrying one of `return_keys`).
+    """
+    faults = []
+    ran = 0
+    for rec in records:
+        it = rec.get("iteration")
+        if "retry" in rec:
+            faults.append(f"iteration {it}: retry {rec['retry']} after "
+                          f"{rec.get('error', '')!r}")
+        if rec.get("skipped_nonfinite_update") or rec.get("update_ok") == 0.0:
+            faults.append(f"iteration {it}: non-finite update skipped")
+        values = [rec[k] for k in return_keys if k in rec]
+        if values:
+            ran += 1
+            if not all(isinstance(v, (int, float)) and math.isfinite(v)
+                       for v in values):
+                faults.append(f"iteration {it}: non-finite return {values}")
+    if ran < expected:
+        faults.append(f"{ran} of {expected} iterations ran")
+    return faults
 
 
 class RunnerBase:

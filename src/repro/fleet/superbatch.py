@@ -64,7 +64,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core import policy as policy_lib
@@ -228,13 +227,13 @@ class FleetProgram:
         noises = {n: uv[1] for n, uv in drawn.items()}
         if self.mesh is None:
             return self.rollout_shard(params, u0s, noises)
-        fn = shard_map(
+        fn = jax.shard_map(
             self.rollout_shard, mesh=self.mesh,
             in_specs=(P(),  # params: replicated
                       {n: P(self.data_axis) for n in self.names},
                       {n: P(None, self.data_axis) for n in self.names}),
             out_specs={n: _TRAJ_DATA_SPEC for n in self.names},
-            check_rep=False)
+            check_vma=False)
         return fn(params, u0s, noises)
 
     # --- the compiled iteration ----------------------------------------------

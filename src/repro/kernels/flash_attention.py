@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from ._compat import tpu_compiler_params
 from .policy import resolve_interpret
 
 _NEG_INF = float("-inf")
@@ -140,7 +139,6 @@ def flash_attention(
         block_q=block_q, block_k=block_k, n_kv_blocks=n_kv_blocks,
         sq=sq, skv=skv,
     )
-    compiler_params = tpu_compiler_params(("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         kern,
         grid=grid,
@@ -160,7 +158,8 @@ def flash_attention(
         ],
         interpret=resolve_interpret(interpret),
         name="flash_attention_fwd",
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qf, kf, vf)
     out = out[:, :sq] if pad_q else out
     return out.reshape(b, hq, sq, d)

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from ._compat import tpu_compiler_params
 from .policy import resolve_interpret
 
 
@@ -139,7 +138,6 @@ def linear_scan(
         _kernel, chunk=chunk, n_chunks=n_chunks,
         decay_before_read=decay_before_read, has_u=has_u,
     )
-    compiler_params = tpu_compiler_params(("parallel", "arbitrary"))
     o, s_fin = pl.pallas_call(
         kern,
         grid=(b, n_chunks),
@@ -162,6 +160,7 @@ def linear_scan(
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=resolve_interpret(interpret),
         name="linear_scan",
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(q, k, v, w, u_in, s0_in)
     return (o[:, :t] if pad else o), s_fin

@@ -72,6 +72,16 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return default_interpret() if interpret is None else interpret
 
 
+# Mosaic's default scoped-VMEM limit on TPU v5e.
+DEFAULT_SCOPED_VMEM = 16 * 2**20
+
+
+def scoped_vmem_limit(need_bytes: int) -> int | None:
+    """`vmem_limit_bytes` for a kernel whose working set is `need_bytes`:
+    None (the compiler default) when the default suffices."""
+    return None if need_bytes <= DEFAULT_SCOPED_VMEM else int(need_bytes)
+
+
 def resolve_use_kernels(use_kernels: bool | None) -> bool:
     """Config `use_kernels` field: an explicit choice wins; None = policy.
     The shared resolver behind HITConfig/ChannelConfig `.kernels_enabled`."""

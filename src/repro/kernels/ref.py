@@ -9,8 +9,11 @@ FLOP/byte behavior as the kernels).
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 
 # --- dg_derivative -----------------------------------------------------------
@@ -74,99 +77,217 @@ def wall_model_tau(u_par: jax.Array, rho_w: jax.Array, *, y_m: float,
 
 # --- fused Navier-Stokes RHS -------------------------------------------------
 # Self-contained single-pass DGSEM RHS for the periodic HIT scenario — the
-# oracle for kernels/rhs.py (the mega-kernel's body calls THIS function on
-# its VMEM block, so kernel and oracle share one op order by construction).
-# The constants and formulas mirror cfd/equations + cfd/dgsem; they are
-# restated here because this module must stay a leaf (imports jax only — the
-# kernels cannot cycle through the cfd package).  Two deliberate deviations
-# from the cfd reference, both bit-identical in exact zeros:
-#   * periodic rolls are slice+concatenate (jnp.roll is a gather that Mosaic
-#     does not lower inside kernel bodies),
-#   * the endpoint surface lift is a concatenation of the two corrected face
-#     slabs around an exact-zero interior (no .at[].add scatter).
+# oracle for kernels/rhs.py.  The kernel body calls `navier_stokes_rhs_planar`
+# on its VMEM block and this module's `navier_stokes_rhs_fused` calls the
+# same function on the whole batch, so kernel and oracle share one op order
+# by construction.  The constants and formulas mirror cfd/equations +
+# cfd/dgsem; they are restated here because this module must stay a leaf
+# (imports jax only — the kernels cannot cycle through the cfd package).
+#
+# Planar layout.  The state (..., Kx, Ky, Kz, n, n, n, C) is held as C
+# planes of shape (P, L): P = n^3 node rows (r = i0 n^2 + i1 n + i2) by
+# L = batch x K^3 element lanes (l = b K^3 + e0 K^2 + e1 K + e2).  Both
+# minor dims are tile-dense for Mosaic (P is a multiple of 8 for even n,
+# a grid block spans a multiple of 128 lanes), and every operation is
+# elementwise, a static roll, a select or a reduction:
+#   * node-axis derivatives (and the split-form two-point sums) are sums
+#     over row offsets o of D[i, i+o] * roll(x, -o s_d) with s_d the row
+#     stride of node axis d — the per-row coefficients come in as (P, 1)
+#     columns (`planar_consts`), zero where i+o falls outside the element;
+#   * the periodic neighbour exchange is a row roll by (n-1) s_d (lo face
+#     <-> hi face of the same element) plus a lane roll by the element
+#     stride t_d with a select at the periodic wrap;
+#   * the surface lift is a select on the face rows, so interior rows keep
+#     their volume term exactly (the + 0 of the cfd reference);
+#   * the Lundgren forcing's whole-box means are a weighted row sum and a
+#     per-environment masked lane sum.
+# Face quantities are evaluated on every row and read only on the face
+# rows, so no operation changes shape.
 
 _GAMMA = 1.4
 _R_GAS = 1.0
 _CP = _GAMMA * _R_GAS / (_GAMMA - 1.0)
-# element / intra-element node axes of the shared (..., Kx, Ky, Kz, n, n, n,
-# C) state layout (cfd/dgsem.py module docstring)
-_ELEM_AXIS = (-7, -6, -5)
-_NODE_AXIS = (-4, -3, -2)
 
 
-def _roll(x, shift: int, axis: int):
-    """Circular shift by +-1 via slice+concatenate (see note above)."""
-    n = x.shape[axis]
-    if shift == -1:
-        parts = (jax.lax.slice_in_dim(x, 1, n, axis=axis),
-                 jax.lax.slice_in_dim(x, 0, 1, axis=axis))
-    else:
-        parts = (jax.lax.slice_in_dim(x, n - 1, n, axis=axis),
-                 jax.lax.slice_in_dim(x, 0, n - 1, axis=axis))
-    return jnp.concatenate(parts, axis=axis)
+class PlanarConsts(NamedTuple):
+    """Per-layout constants of the planar RHS (built by `planar_consts`).
+
+    coef  (3, 2n-1, P, 1)  D[i_d, i_d + o] per node axis d and row offset
+                           o = -(n-1)..n-1, zero outside the element
+    rmask (6, P, 1)        1.0 on the lo / hi face rows of node axis d
+                           (order lo0, hi0, lo1, hi1, lo2, hi2)
+    lmask (6, 1, L)        1.0 on the first / last element lanes of
+                           element axis d (order first0, last0, ...)
+    emask (E, 1, L)        1.0 on the lanes of environment j
+    wq    (P, 1)           quadrature weight of each node (unit mass)
+    """
+
+    coef: jax.Array
+    rmask: jax.Array
+    lmask: jax.Array
+    emask: jax.Array
+    wq: jax.Array
 
 
-def _deriv_along(u, d_matrix, direction: int):
-    axis = _NODE_AXIS[direction] + u.ndim
-    moved = jnp.moveaxis(u, axis, -1)
-    return jnp.moveaxis(moved @ d_matrix.T, -1, axis)
+def to_planar(u: jax.Array) -> jax.Array:
+    """(B, Kx, Ky, Kz, n, n, n, C) -> (C, n^3, B Kx Ky Kz)."""
+    b, kx, ky, kz, n, _, _, c = u.shape
+    return jnp.transpose(u, (7, 4, 5, 6, 0, 1, 2, 3)).reshape(
+        c, n**3, b * kx * ky * kz)
 
 
-def _face_slices(u, direction: int):
-    axis = _NODE_AXIS[direction] + u.ndim
-    lo = jax.lax.index_in_dim(u, 0, axis, keepdims=False)
-    hi = jax.lax.index_in_dim(u, u.shape[axis] - 1, axis, keepdims=False)
-    return lo, hi
+def from_planar(x: jax.Array, mesh: tuple[int, ...]) -> jax.Array:
+    """Inverse of `to_planar`; `mesh` is (Kx, Ky, Kz, n, n, n, C)."""
+    kx, ky, kz, n, _, _, c = mesh
+    b = x.shape[-1] // (kx * ky * kz)
+    x = x.reshape(c, n, n, n, b, kx, ky, kz)
+    return jnp.transpose(x, (4, 5, 6, 7, 1, 2, 3, 0))
 
 
-def _neighbor_traces(u, direction: int):
-    lo, hi = _face_slices(u, direction)
-    elem_axis = _ELEM_AXIS[direction] + lo.ndim + 1  # one axis was dropped
-    return hi, _roll(lo, -1, elem_axis)
+def _node_index(n: int) -> list[jax.Array]:
+    """Node index along each node axis of every planar row."""
+    rows = jnp.arange(n**3)
+    return [rows // n ** (2 - d) % n for d in range(3)]
 
 
-def _surface_lift(du, jump_right, jump_left, direction: int,
-                  inv_w_end: tuple[float, float]):
-    axis = _NODE_AXIS[direction] + du.ndim
-    moved = jnp.moveaxis(du, axis, -1)
-    inv_w0, inv_wn = inv_w_end
-    corr = jnp.concatenate([
-        (-inv_w0 * jump_left)[..., None],
-        jnp.zeros(moved.shape[:-1] + (moved.shape[-1] - 2,), moved.dtype),
-        (inv_wn * jump_right)[..., None],
-    ], axis=-1)
-    return jnp.moveaxis(moved + corr, -1, axis)
+def deriv_coef(d_matrix: jax.Array, n: int) -> jax.Array:
+    """(3, 2n-1, n^3, 1): D[i_d, i_d + o] per node axis d and row offset
+    o = -(n-1)..n-1 of every planar row, zero where i_d + o is outside the
+    element."""
+    d32 = d_matrix.astype(jnp.float32)
+    offsets = jnp.arange(-(n - 1), n)[:, None]
+    coef = []
+    for node in _node_index(n):
+        m = node[None, :] + offsets
+        vals = d32[jnp.broadcast_to(node, m.shape), jnp.clip(m, 0, n - 1)]
+        coef.append(jnp.where((m >= 0) & (m < n), vals, 0.0))
+    return jnp.stack(coef)[..., None]
+
+
+def planar_deriv(x, coef, n: int, d: int, roll=jnp.roll):
+    """sum_m D[i_d, m] x[.., m, ..] along node axis d of a (P, L) plane;
+    `coef` from `deriv_coef` (array or VMEM ref)."""
+    s = n ** (2 - d)
+    out = None
+    for j, o in enumerate(range(-(n - 1), n)):
+        shift = -o * s % x.shape[0]
+        term = coef[d, j] * (roll(x, shift, 0) if shift else x)
+        out = term if out is None else out + term
+    return out
+
+
+def planar_consts(d_matrix: jax.Array, w: jax.Array, n: int, k: int,
+                  n_env: int) -> PlanarConsts:
+    """Constants for `n_env` periodic K^3-element meshes of n^3 nodes."""
+    f32 = jnp.float32
+    node = _node_index(n)
+    rmask = jnp.stack([node[d] == e for d in range(3) for e in (0, n - 1)])
+    lanes = jnp.arange(n_env * k**3)
+    elem = [lanes // k ** (2 - d) % k for d in range(3)]
+    lmask = jnp.stack([elem[d] == e for d in range(3) for e in (0, k - 1)])
+    emask = lanes[None, :] // k**3 == jnp.arange(n_env)[:, None]
+    w2 = w.astype(f32) * 0.5  # reference [-1,1] -> unit mass
+    wq = w2[node[0]] * w2[node[1]] * w2[node[2]]
+    return PlanarConsts(
+        coef=deriv_coef(d_matrix, n),
+        rmask=rmask.astype(f32)[..., None],
+        lmask=lmask.astype(f32)[:, None, :],
+        emask=emask.astype(f32)[:, None, :],
+        wq=wq[:, None])
+
+
+def kernel_roll(x, shift: int, axis: int):
+    """`jnp.roll` by a static shift as Mosaic's rotate (kernel bodies);
+    the same values as `jnp.roll`, which the XLA oracle uses."""
+    return pltpu.roll(x, shift, axis)
+
+
+class _Planar:
+    """Stencil operators of one planar block (n^3 node rows, K^3-element
+    lanes per environment)."""
+
+    def __init__(self, consts, n: int, k: int, roll):
+        self.c, self.n, self.k, self._roll_fn = consts, n, k, roll
+
+    def roll(self, x, shift: int, axis: int):
+        shift %= x.shape[axis]
+        return x if shift == 0 else self._roll_fn(x, shift, axis)
+
+    def deriv(self, x, d: int):
+        return planar_deriv(x, self.c.coef, self.n, d, self._roll_fn)
+
+    def next_elem(self, x, d: int):
+        """x of the +1 neighbour along element axis d (periodic)."""
+        t = self.k ** (2 - d)
+        last = self.c.lmask[2 * d + 1] > 0.5
+        return jnp.where(last, self.roll(x, (self.k - 1) * t, 1),
+                         self.roll(x, -t, 1))
+
+    def prev_elem(self, x, d: int):
+        """x of the -1 neighbour along element axis d (periodic)."""
+        t = self.k ** (2 - d)
+        first = self.c.lmask[2 * d] > 0.5
+        return jnp.where(first, self.roll(x, -(self.k - 1) * t, 1),
+                         self.roll(x, t, 1))
+
+    def lo_of_next(self, x, d: int):
+        """On hi face rows: the next element's lo face trace of x."""
+        return self.next_elem(
+            self.roll(x, (self.n - 1) * self.n ** (2 - d), 0), d)
+
+    def hi_of_prev(self, x, d: int):
+        """On lo face rows: x on the previous element's hi face rows."""
+        return self.prev_elem(
+            self.roll(x, -(self.n - 1) * self.n ** (2 - d), 0), d)
+
+    def lift(self, vol, jump_right, jump_left, d: int,
+             inv_w_end: tuple[float, float]):
+        """Surface lift: volume term plus the face corrections on the lo /
+        hi face rows of node axis d."""
+        inv_w0, inv_wn = inv_w_end
+        lo = self.c.rmask[2 * d] > 0.5
+        hi = self.c.rmask[2 * d + 1] > 0.5
+        return jnp.where(lo, vol + (-inv_w0 * jump_left),
+                         jnp.where(hi, vol + inv_wn * jump_right, vol))
+
+    def env_mean(self, x, n_elem_total: int):
+        """Whole-box quadrature mean per environment, on every lane."""
+        col = jnp.sum(self.c.wq[...] * x, axis=0, keepdims=True)
+        out = jnp.zeros_like(col)
+        for j in range(self.c.emask.shape[0]):
+            mine = self.c.emask[j] > 0.5
+            tot = jnp.sum(jnp.where(mine, col, 0.0), axis=1, keepdims=True)
+            out = jnp.where(mine, tot, out)
+        return out / n_elem_total
 
 
 def _primitives(u):
-    rho = u[..., 0]
-    vel = u[..., 1:4] / rho[..., None]
-    kinetic = 0.5 * rho * jnp.sum(vel * vel, axis=-1)
-    p = (_GAMMA - 1.0) * (u[..., 4] - kinetic)
+    rho = u[0]
+    vel = [u[1 + i] / rho for i in range(3)]
+    kinetic = 0.5 * rho * (vel[0] * vel[0] + vel[1] * vel[1]
+                           + vel[2] * vel[2])
+    p = (_GAMMA - 1.0) * (u[4] - kinetic)
     temp = p / (rho * _R_GAS)
     return rho, vel, p, temp
 
 
 def _mom_flux(base, per_comp, p, direction: int):
-    """Momentum flux columns base_i (+ p on the flux-direction component),
-    assembled per component — the pressure add targets one channel without a
-    scatter or a captured one-hot constant (Pallas-body constraints)."""
+    """Momentum flux components base * per_comp_i, plus p on the flux
+    direction's component."""
     cols = []
     for i in range(3):
-        c = base * per_comp[..., i]
+        c = base * per_comp[i]
         if i == direction:
             c = c + p
-        cols.append(c[..., None])
-    return jnp.concatenate(cols, axis=-1)
+        cols.append(c)
+    return cols
 
 
 def _advective_flux(u, direction: int):
     rho, vel, p, _ = _primitives(u)
-    vn = vel[..., direction]
-    f_rho = u[..., 1 + direction]
-    f_mom = _mom_flux(vn, u[..., 1:4], p, direction)
-    f_e = (u[..., 4] + p) * vn
-    return jnp.concatenate([f_rho[..., None], f_mom, f_e[..., None]], axis=-1)
+    vn = vel[direction]
+    f_mom = _mom_flux(vn, u[1:4], p, direction)
+    return [u[1 + direction], *f_mom, (u[4] + p) * vn]
 
 
 def _lax_friedrichs(u_l, u_r, direction: int):
@@ -174,66 +295,143 @@ def _lax_friedrichs(u_l, u_r, direction: int):
     rho_r, vel_r, p_r, _ = _primitives(u_r)
     c_l = jnp.sqrt(_GAMMA * p_l / rho_l)
     c_r = jnp.sqrt(_GAMMA * p_r / rho_r)
-    lam = jnp.maximum(jnp.abs(vel_l[..., direction]) + c_l,
-                      jnp.abs(vel_r[..., direction]) + c_r)
+    lam = jnp.maximum(jnp.abs(vel_l[direction]) + c_l,
+                      jnp.abs(vel_r[direction]) + c_r)
     f_l = _advective_flux(u_l, direction)
     f_r = _advective_flux(u_r, direction)
-    return 0.5 * (f_l + f_r) - 0.5 * lam[..., None] * (u_r - u_l)
+    return [0.5 * (a + b) - 0.5 * lam * (r - l)
+            for a, b, l, r in zip(f_l, f_r, u_l, u_r)]
 
 
-def _flux_differencing(prim, d_matrix, direction: int):
-    """Split-form volume integral with the Kennedy-Gruber two-point flux
-    (all-arithmetic-mean; cfd/equations.kennedy_gruber_flux inlined)."""
-    def pairwise(q, is_vec):
-        a = q.ndim + _NODE_AXIS[direction] + (0 if is_vec else 1)
-        moved = jnp.moveaxis(q, a, -2 if is_vec else -1)
-        if is_vec:
-            return moved[..., :, None, :], moved[..., None, :, :]
-        return moved[..., :, None], moved[..., None, :]
-
+def _flux_differencing(prim, geo: _Planar, direction: int):
+    """Split-form volume integral 2 sum_m D[i, m] F#(q_i, q_m) with the
+    Kennedy-Gruber two-point flux (all-arithmetic-mean;
+    cfd/equations.kennedy_gruber_flux inlined)."""
     rho, vel, p, e = prim
-    rho_a, rho_b = pairwise(rho, False)
-    vel_a, vel_b = pairwise(vel, True)
-    p_a, p_b = pairwise(p, False)
-    e_a, e_b = pairwise(e, False)
-    rho_m = 0.5 * (rho_a + rho_b)
-    vel_m = 0.5 * (vel_a + vel_b)
-    p_m = 0.5 * (p_a + p_b)
-    e_m = 0.5 * (e_a + e_b)
-    vn = vel_m[..., direction]
-    f_rho = rho_m * vn
-    f_mom = _mom_flux(f_rho, vel_m, p_m, direction)
-    f_e = f_rho * e_m + p_m * vn
-    f_pair = jnp.concatenate([f_rho[..., None], f_mom, f_e[..., None]],
-                             axis=-1)
-    out = 2.0 * jnp.einsum("ij,...ijc->...ic", d_matrix, f_pair)
-    return jnp.moveaxis(out, -2, _NODE_AXIS[direction] + out.ndim)
+    s = geo.n ** (2 - direction)
+    acc = [None] * 5
+    for j, o in enumerate(range(-(geo.n - 1), geo.n)):
+        def partner(q):
+            return geo.roll(q, -o * s, 0)
+
+        rho_m = 0.5 * (rho + partner(rho))
+        vel_m = [0.5 * (v + partner(v)) for v in vel]
+        p_m = 0.5 * (p + partner(p))
+        e_m = 0.5 * (e + partner(e))
+        vn = vel_m[direction]
+        f_rho = rho_m * vn
+        f_mom = _mom_flux(f_rho, vel_m, p_m, direction)
+        f_e = f_rho * e_m + p_m * vn
+        c = geo.c.coef[direction, j]
+        for ch, f in enumerate([f_rho, *f_mom, f_e]):
+            acc[ch] = c * f if acc[ch] is None else acc[ch] + c * f
+    return [2.0 * a for a in acc]
 
 
-def _viscous_flux(u, grad_prim, nu_t, direction: int, mu: float,
+def _viscous_flux(u, grad, nu_t, direction: int, mu: float,
                   prandtl: float, prandtl_turb: float):
+    """grad[c][d] = d q_c / d x_d for q = (v_x, v_y, v_z, T)."""
     rho, vel, _, _ = _primitives(u)
-    grad_v = grad_prim[..., 0:3, :]
-    grad_t = grad_prim[..., 3, :]
-    s_ij = 0.5 * (grad_v + jnp.swapaxes(grad_v, -1, -2))
-    div_v = grad_v[..., 0, 0] + grad_v[..., 1, 1] + grad_v[..., 2, 2]
+    div_v = grad[0][0] + grad[1][1] + grad[2][2]
     mu_eff = mu + rho * nu_t
     third = (2.0 / 3.0) * mu_eff * div_v
     # column d of tau_ij = 2 mu_eff S_ij - (2/3) mu_eff div(v) delta_ij —
     # only the flux direction's column is needed, so no (3,3) tensor forms
-    cols = []
+    tau_d = []
     for i in range(3):
-        c = 2.0 * mu_eff * s_ij[..., i, direction]
+        s_id = 0.5 * (grad[i][direction] + grad[direction][i])
+        c = 2.0 * mu_eff * s_id
         if i == direction:
             c = c - third
-        cols.append(c[..., None])
-    tau_d = jnp.concatenate(cols, axis=-1)
+        tau_d.append(c)
     k_eff = _CP * (mu / prandtl + rho * nu_t / prandtl_turb)
-    q_d = -k_eff * grad_t[..., direction]
-    work = jnp.sum(tau_d * vel, axis=-1)
-    zero = jnp.zeros_like(rho)
-    return jnp.concatenate([zero[..., None], tau_d, (work - q_d)[..., None]],
-                           axis=-1)
+    q_d = -k_eff * grad[3][direction]
+    work = tau_d[0] * vel[0] + tau_d[1] * vel[1] + tau_d[2] * vel[2]
+    return [jnp.zeros_like(rho), *tau_d, work - q_d]
+
+
+def navier_stokes_rhs_planar(
+    u, cs, consts: PlanarConsts, *, n: int, k: int,
+    inv_w_end: tuple[float, float], jac: float, delta: float, mu: float,
+    prandtl: float, prandtl_turb: float, forcing_a0: float, k_tke: float,
+    roll=jnp.roll,
+) -> list:
+    """The fused RHS on planar operands — the body of kernels/rhs.py.
+
+    u: 5 conservative planes (indexable, e.g. a (5, P, L) array or a VMEM
+    ref); cs: the (P, L) Smagorinsky coefficient plane; consts for this
+    block (`planar_consts`).  `roll` is `jnp.roll` under XLA and
+    `kernel_roll` in a kernel body.  Returns the 5 float32 RHS planes.
+
+    Pipeline (the op order of cfd/solver.navier_stokes_rhs): primitive
+    decode -> BR1 gradient of (v, T) -> Smagorinsky nu_t -> per-direction
+    split-form Kennedy-Gruber volume + LLF surface + BR1 viscous
+    divergence -> whole-box quadrature-mean forcing.
+    """
+    f32 = jnp.float32
+    geo = _Planar(consts, n, k, roll)
+    u = [u[c].astype(f32) for c in range(5)]
+    cs = cs[...].astype(f32)
+
+    rho, vel, p, temp = _primitives(u)
+    e_spec = u[4] / rho
+    prim = (rho, vel, p, e_spec)
+    q_prim = [*vel, temp]
+
+    # BR1 gradient of (v, T): central interface averages, periodic wrap
+    grad = [[None] * 3 for _ in range(4)]
+    for d in range(3):
+        for c, q in enumerate(q_prim):
+            vol = geo.deriv(q, d)
+            q_star_right = 0.5 * (q + geo.lo_of_next(q, d))
+            q_star_left = geo.hi_of_prev(q_star_right, d)
+            g = geo.lift(vol, q_star_right - q, q_star_left - q, d,
+                         inv_w_end)
+            grad[c][d] = g * jac
+
+    # Smagorinsky eddy viscosity (paper Eq. 3)
+    s2 = None
+    for i in range(3):
+        for j in range(3):
+            s_ij = 0.5 * (grad[i][j] + grad[j][i])
+            s2 = s_ij * s_ij if s2 is None else s2 + s_ij * s_ij
+    s_mag = jnp.sqrt(2.0 * s2 + 1e-30)
+    nu_t = (cs * delta) ** 2 * s_mag
+
+    rhs = None
+    for d in range(3):
+        # advective: split-form volume + LLF surface
+        vol_adv = _flux_differencing(prim, geo, d)
+        f_adv_nodes = _advective_flux(u, d)
+        u_right = [geo.lo_of_next(x, d) for x in u]
+        f_star_adv = _lax_friedrichs(u, u_right, d)
+        # viscous: standard derivative volume + central surface
+        f_visc = _viscous_flux(u, grad, nu_t, d, mu, prandtl, prandtl_turb)
+        div_d = []
+        for c in range(5):
+            vol_visc = geo.deriv(f_visc[c], d)
+            f_star_visc = 0.5 * (f_visc[c] + geo.lo_of_next(f_visc[c], d))
+            vol = vol_adv[c] - vol_visc
+            f_star = f_star_adv[c] - f_star_visc
+            f_nodes = f_adv_nodes[c] - f_visc[c]
+            f_star_left = geo.hi_of_prev(f_star, d)
+            div_d.append(geo.lift(vol, f_star - f_nodes,
+                                  f_star_left - f_nodes, d, inv_w_end) * jac)
+        rhs = ([-x for x in div_d] if rhs is None
+               else [r - x for r, x in zip(rhs, div_d)])
+
+    # Lundgren linear forcing + proportional TKE controller.  Whole meshes
+    # sit in the block, so the global quadrature means are computed in-pass.
+    n_elem_total = k**3
+    mom = u[1:4]
+    mom_fluct = [m - geo.env_mean(m, n_elem_total) for m in mom]
+    ke_density = 0.5 * (mom[0] * vel[0] + mom[1] * vel[1] + mom[2] * vel[2])
+    k_now = geo.env_mean(ke_density, n_elem_total)
+    a_eff = forcing_a0 * jnp.clip(
+        k_tke / jnp.maximum(k_now, 0.1 * k_tke), 0.0, 3.0)
+    f_mom = [a_eff * m for m in mom_fluct]
+    f_e = f_mom[0] * vel[0] + f_mom[1] * vel[1] + f_mom[2] * vel[2]
+    return [rhs[0], *(r + f for r, f in zip(rhs[1:4], f_mom)), rhs[4] + f_e]
 
 
 def navier_stokes_rhs_fused(
@@ -251,97 +449,30 @@ def navier_stokes_rhs_fused(
     forcing_a0: float,
     k_tke: float,
 ) -> jax.Array:
-    """One fused periodic-HIT Navier-Stokes RHS evaluation — the mega-kernel
-    oracle (kernels/rhs.py runs this exact function on its VMEM block).
+    """One fused periodic-HIT Navier-Stokes RHS evaluation — the
+    mega-kernel oracle (kernels/rhs.py runs `navier_stokes_rhs_planar` on
+    each VMEM block; this runs it on the whole batch at once).
 
-    u: (..., Kx, Ky, Kz, n, n, n, 5) conservative state (any leading batch);
+    u: (..., K, K, K, n, n, n, 5) conservative state (any leading batch);
     cs_nodes: per-node Smagorinsky coefficient, shaped like u[..., 0];
     d_matrix: (n, n) Lagrange derivative matrix; w: (n,) GLL quadrature
     weights.  Scalars: `inv_w_end` endpoint inverse weights, `jac` the
     reference-to-physical scaling, `delta` the LES filter width, gas
     parameters and the Lundgren forcing controller (forcing_a0, k_tke).
-
-    Pipeline (identical op order to cfd/solver.navier_stokes_rhs, the
-    parity contract): primitive decode -> BR1 gradient of (v, T) ->
-    Smagorinsky nu_t -> per-direction split-form Kennedy-Gruber volume +
-    LLF surface + BR1 viscous divergence -> whole-box quadrature-mean
-    forcing.  All math in float32; the result is cast to u.dtype (bf16
-    in/out for the mixed-precision rollout).
+    All math in float32; the result is cast to u.dtype (bf16 in/out for
+    the mixed-precision rollout).
     """
-    out_dtype = u.dtype
-    f32 = jnp.float32
-    u = u.astype(f32)
-    cs_nodes = cs_nodes.astype(f32)
-    d_matrix = d_matrix.astype(f32)
-    w2 = w.astype(f32) * 0.5  # reference [-1,1] -> unit mass
-
-    rho, vel, p, temp = _primitives(u)
-    e_spec = u[..., 4] / rho
-    prim = (rho, vel, p, e_spec)
-    q_prim = jnp.concatenate([vel, temp[..., None]], axis=-1)
-
-    # BR1 gradient of (v, T): central interface averages, periodic wrap
-    grads = []
-    for d in range(3):
-        vol = _deriv_along(q_prim, d_matrix, d)
-        q_left, q_right = _neighbor_traces(q_prim, d)
-        q_star_right = 0.5 * (q_left + q_right)
-        lo, hi = _face_slices(q_prim, d)
-        q_star_left = _roll(q_star_right, 1,
-                            _ELEM_AXIS[d] + q_star_right.ndim + 1)
-        g = _surface_lift(vol, q_star_right - hi, q_star_left - lo, d,
-                          inv_w_end)
-        grads.append(g * jac)
-    grad_prim = jnp.stack(grads, axis=-1)
-
-    # Smagorinsky eddy viscosity (paper Eq. 3)
-    grad_v = grad_prim[..., 0:3, :]
-    s_ij = 0.5 * (grad_v + jnp.swapaxes(grad_v, -1, -2))
-    s_mag = jnp.sqrt(2.0 * jnp.sum(s_ij * s_ij, axis=(-1, -2)) + 1e-30)
-    nu_t = (cs_nodes * delta) ** 2 * s_mag
-
-    rhs = None
-    for d in range(3):
-        # advective: split-form volume + LLF surface
-        vol_adv = _flux_differencing(prim, d_matrix, d)
-        f_adv_nodes = _advective_flux(u, d)
-        u_left, u_right = _neighbor_traces(u, d)
-        f_star_adv = _lax_friedrichs(u_left, u_right, d)
-        # viscous: standard derivative volume + central surface
-        f_visc = _viscous_flux(u, grad_prim, nu_t, d, mu, prandtl,
-                               prandtl_turb)
-        vol_visc = _deriv_along(f_visc, d_matrix, d)
-        fv_left, fv_right = _neighbor_traces(f_visc, d)
-        f_star_visc = 0.5 * (fv_left + fv_right)
-
-        vol = vol_adv - vol_visc
-        f_star = f_star_adv - f_star_visc
-        f_nodes = f_adv_nodes - f_visc
-        lo, hi = _face_slices(f_nodes, d)
-        f_star_left = _roll(f_star, 1, _ELEM_AXIS[d] + f_star.ndim + 1)
-        div_d = _surface_lift(vol, f_star - hi, f_star_left - lo, d,
-                              inv_w_end) * jac
-        rhs = -div_d if rhs is None else rhs - div_d
-
-    # Lundgren linear forcing + proportional TKE controller.  The whole mesh
-    # is resident in the kernel block, so the global quadrature means are
-    # computed in-pass.
-    n_elem_total = u.shape[-7] * u.shape[-6] * u.shape[-5]
-    mom = u[..., 1:4]
-    mom_mean = jnp.einsum("...xyzijkc,i,j,k->...c", mom, w2, w2,
-                          w2) / n_elem_total
-    mom_fluct = mom - mom_mean[..., None, None, None, None, None, None, :]
-    ke_density = 0.5 * jnp.sum(mom * vel, axis=-1, keepdims=True)
-    k_now = jnp.einsum("...xyzijkc,i,j,k->...c", ke_density, w2, w2,
-                       w2)[..., 0] / n_elem_total
-    a_eff = forcing_a0 * jnp.clip(
-        k_tke / jnp.maximum(k_now, 0.1 * k_tke), 0.0, 3.0)
-    a_eff = a_eff[..., None, None, None, None, None, None]
-    f_mom = a_eff[..., None] * mom_fluct
-    f_e = jnp.sum(f_mom * vel, axis=-1, keepdims=True)
-    forcing = jnp.concatenate([jnp.zeros_like(rhs[..., :1]), f_mom, f_e],
-                              axis=-1)
-    return (rhs + forcing).astype(out_dtype)
+    mesh = u.shape[-7:]
+    k, n = mesh[0], mesh[3]
+    ub = u.reshape((-1,) + mesh)
+    csb = cs_nodes.reshape((-1,) + mesh[:-1] + (1,))
+    consts = planar_consts(d_matrix, w, n, k, ub.shape[0])
+    planes = navier_stokes_rhs_planar(
+        to_planar(ub), to_planar(csb)[0], consts, n=n, k=k,
+        inv_w_end=inv_w_end, jac=jac, delta=delta, mu=mu, prandtl=prandtl,
+        prandtl_turb=prandtl_turb, forcing_a0=forcing_a0, k_tke=k_tke)
+    out = from_planar(jnp.stack(planes), mesh)
+    return out.astype(u.dtype).reshape(u.shape)
 
 
 # --- flash attention ---------------------------------------------------------

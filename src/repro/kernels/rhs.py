@@ -7,21 +7,22 @@ intermediate is mesh-sized, so an RK5 substep moves ~30 state-sized buffers
 through HBM per RHS call.  This kernel computes the whole evaluation —
 DG derivative -> viscous/convective flux -> Smagorinsky eddy viscosity ->
 divergence + forcing — in a single launch with every intermediate resident
-in VMEM: per grid step it reads one element-batch block of (u, cs_nodes)
-and writes one block of rhs (2 state-sized HBM transfers total).
+in VMEM: per grid step it reads one block of (u, cs_nodes) and writes one
+block of rhs.
 
-Grid layout: the environment batch is flattened and gridded in blocks of
-`block_e` WHOLE meshes, (block_e, Kx, Ky, Kz, n, n, n, 5) per block.  A
-block holds complete meshes because the RHS is not element-local: the
-surface exchange couples neighbor elements (periodic rolls along the
-element axes) and the Lundgren forcing needs whole-box quadrature means —
-both stay in-kernel when the mesh is resident.  At paper scale a mesh is
-small (24-DOF HIT: 4^3 elements x 6^3 nodes x 5 channels = 540 KB in f32),
-so mesh + intermediates fit VMEM comfortably; `block_e` trades VMEM
-footprint against grid-step count for large env batches.
+Layout: the kernel works on the planar layout of kernels/ref.py — C planes
+of (P, L) = (n^3 node rows, batch x K^3 element lanes) — so both minor dims
+of every block are tile-dense (the natural (..., n, n, n, 5) layout ends in
+(6, 5) at 24 DOF, which pads ~40x to the (8, 128) tile).  The wrapper
+transposes the state into and out of that layout.  A grid step holds
+`block_e` WHOLE meshes, because the RHS is not element-local: the surface
+exchange couples neighbour elements (periodic) and the Lundgren forcing
+needs whole-box quadrature means — both stay in-kernel when the mesh is
+resident.  `block_e` is rounded up so a block spans a multiple of 128 lanes
+(2 meshes of 4^3 elements), or covers the whole batch when it is smaller.
 
-The kernel body calls `ref.navier_stokes_rhs_fused` on its block values —
-kernel and oracle share one op order by construction, which is what the
+The kernel body calls `ref.navier_stokes_rhs_planar` on its block — kernel
+and oracle share one op order by construction, which is what the
 `kernel_parity` gate (tests/test_kernel_parity.py) pins.  Internal math is
 float32 regardless of I/O dtype; bf16 in/out serves the mixed-precision
 rollout (HITConfig.precision = "bf16").
@@ -29,21 +30,51 @@ rollout (HITConfig.precision = "bf16").
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
-from .policy import resolve_interpret
+from .policy import resolve_interpret, scoped_vmem_limit
 
 
-def _kernel(u_ref, cs_ref, d_ref, w_ref, rhs_ref, *, inv_w_end, jac, delta,
-            mu, prandtl, prandtl_turb, forcing_a0, k_tke):
-    rhs_ref[...] = ref.navier_stokes_rhs_fused(
-        u_ref[...], cs_ref[...], d_ref[...], w_ref[...],
-        inv_w_end=inv_w_end, jac=jac, delta=delta, mu=mu, prandtl=prandtl,
-        prandtl_turb=prandtl_turb, forcing_a0=forcing_a0, k_tke=k_tke)
+def _kernel(u_ref, cs_ref, coef_ref, rmask_ref, lmask_ref, emask_ref, wq_ref,
+            rhs_ref, *, n, k, **kw):
+    consts = ref.PlanarConsts(coef=coef_ref, rmask=rmask_ref,
+                              lmask=lmask_ref, emask=emask_ref, wq=wq_ref)
+    planes = ref.navier_stokes_rhs_planar(u_ref, cs_ref, consts, n=n, k=k,
+                                          roll=ref.kernel_roll, **kw)
+    for c, plane in enumerate(planes):
+        rhs_ref[c] = plane.astype(rhs_ref.dtype)
+
+
+def envs_per_block(b: int, k: int, block_e: int) -> int:
+    """Meshes per grid step: `block_e` rounded up to a 128-lane multiple,
+    or the whole batch when it fits one such block."""
+    aligned = 128 // math.gcd(128, k**3)
+    if b <= aligned:
+        return b
+    return aligned * max(1, -(-block_e // aligned))
+
+
+def vmem_limit_bytes(n: int, lanes: int) -> int | None:
+    """Scoped VMEM this block needs, or None if the default suffices.
+
+    Counted in (P, lanes) f32 planes padded to the (8, 128) tile: the
+    double-buffered u, rhs and cs blocks (22), the resident (P, 1)
+    constant columns (3 (2n-1) + 7, each padded to 128 lanes) and 160
+    planes of intermediates.  Mosaic's own count for the body at 128
+    lanes is ~110 intermediate planes at n = 6 and ~145 at n = 8 (its
+    out-of-VMEM reports for 24- and 32-DOF blocks on v5e).
+    """
+    p = -(-n**3 // 8) * 8
+    plane = p * max(128, -(-lanes // 128) * 128) * 4
+    column = p * 128 * 4
+    return scoped_vmem_limit((22 + 160) * plane
+                             + (3 * (2 * n - 1) + 7) * column)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -68,16 +99,16 @@ def fused_navier_stokes_rhs(
 ) -> jax.Array:
     """Fused RHS for an arbitrary batch of HIT meshes.
 
-    u: (..., Kx, Ky, Kz, n, n, n, 5); cs_nodes shaped like u[..., 0];
+    u: (..., K, K, K, n, n, n, 5); cs_nodes shaped like u[..., 0];
     d_matrix (n, n); w (n,) GLL weights; scalars as in the oracle.  Returns
     the RHS with u's shape and dtype.  Matches ref.navier_stokes_rhs_fused.
     """
     mesh = u.shape[-7:]
-    n = mesh[3]
+    k, n = mesh[0], mesh[3]
     ub = u.reshape((-1,) + mesh)
-    csb = cs_nodes.reshape((-1,) + mesh[:-1])
+    csb = cs_nodes.reshape((-1,) + mesh[:-1] + (1,))
     b = ub.shape[0]
-    block_e = max(1, min(block_e, b))
+    block_e = envs_per_block(b, k, block_e)
     pad = (-b) % block_e
     if pad:
         # pad with copies of the first mesh: every padded lane is a valid
@@ -85,22 +116,28 @@ def fused_navier_stokes_rhs(
         ub = jnp.concatenate(
             [ub, jnp.broadcast_to(ub[:1], (pad,) + mesh)], axis=0)
         csb = jnp.concatenate(
-            [csb, jnp.broadcast_to(csb[:1], (pad,) + mesh[:-1])], axis=0)
+            [csb, jnp.broadcast_to(csb[:1], (pad,) + csb.shape[1:])], axis=0)
     bp = b + pad
+    p, lanes = n**3, block_e * k**3
+    consts = ref.planar_consts(d_matrix, w, n, k, block_e)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
-        functools.partial(_kernel, inv_w_end=inv_w_end, jac=jac, delta=delta,
-                          mu=mu, prandtl=prandtl, prandtl_turb=prandtl_turb,
-                          forcing_a0=forcing_a0, k_tke=k_tke),
+        functools.partial(_kernel, n=n, k=k, inv_w_end=inv_w_end, jac=jac,
+                          delta=delta, mu=mu, prandtl=prandtl,
+                          prandtl_turb=prandtl_turb, forcing_a0=forcing_a0,
+                          k_tke=k_tke),
         grid=(bp // block_e,),
         in_specs=[
-            pl.BlockSpec((block_e,) + mesh, lambda i: (i,) + (0,) * 7),
-            pl.BlockSpec((block_e,) + mesh[:-1], lambda i: (i,) + (0,) * 6),
-            pl.BlockSpec((n, n), lambda i: (0, 0)),
-            pl.BlockSpec((n,), lambda i: (0,)),
+            pl.BlockSpec((5, p, lanes), lambda i: (0, 0, i)),
+            pl.BlockSpec((p, lanes), lambda i: (0, i)),
+            whole, whole, whole, whole, whole,
         ],
-        out_specs=pl.BlockSpec((block_e,) + mesh, lambda i: (i,) + (0,) * 7),
-        out_shape=jax.ShapeDtypeStruct((bp,) + mesh, u.dtype),
+        out_specs=pl.BlockSpec((5, p, lanes), lambda i: (0, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((5, p, bp * k**3), u.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit_bytes(n, lanes)),
         interpret=resolve_interpret(interpret),
         name="fused_ns_rhs",
-    )(ub, csb, d_matrix, w)
-    return out[:b].reshape(u.shape)
+    )(ref.to_planar(ub), ref.to_planar(csb)[0], *consts)
+    return ref.from_planar(out, mesh)[:b].reshape(u.shape)

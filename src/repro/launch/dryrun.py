@@ -53,7 +53,7 @@ def _memory_analysis(compiled) -> dict:
 
 def _cost_analysis(compiled) -> dict:
     try:
-        ca = hlo_analysis.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         return {k: float(v) for k, v in ca.items()
                 if k in ("flops", "bytes accessed", "optimal_seconds",
                          "utilization operand")}
@@ -291,7 +291,7 @@ def run_relexi_cell(dof: int = 24, n_envs: int = 256, multi_pod: bool = False,
         shape = (2, 16, 4, 4) if multi_pod else (16, 4, 4)
         axes = (("pod", "data", "mx", "my") if multi_pod
                 else ("data", "mx", "my"))
-        mesh = jax.make_mesh(shape, axes)
+        mesh = mesh_lib.auto_mesh(shape, axes)
     else:
         mesh = mesh_lib.make_production_mesh(multi_pod=multi_pod)
     n_chips = 512 if multi_pod else 256

@@ -60,18 +60,6 @@ class CollectiveStats:
         return sum(self.bytes_by_kind.values())
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """`compiled.cost_analysis()` normalized to one flat dict.
-
-    jax has flipped this API between a per-program list of dicts and a plain
-    dict across versions; every consumer here wants the single-program dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 def collective_bytes(hlo_text: str) -> CollectiveStats:
     """Per-device collective traffic from optimized HLO text (see module
     docstring for the per-op convention)."""

@@ -32,13 +32,22 @@ import os
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *,
+              devices=None) -> Mesh:
+    """`jax.make_mesh` with *Auto* axes.  jax.make_mesh defaults to
+    Explicit axes, on which `with_sharding_constraint` and the fleet's
+    `NamedSharding` placements are refused; every mesh here is Auto."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def _split_data_model(n: int) -> tuple[int, int]:
@@ -53,7 +62,7 @@ def _split_data_model(n: int) -> tuple[int, int]:
 def make_host_mesh():
     """Whatever devices exist, as a (data, model) mesh — tests / examples."""
     data, model = _split_data_model(len(jax.devices()))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def init_distributed(*, coordinator: str | None = None,
@@ -97,7 +106,7 @@ def make_fleet_mesh(*, model: int = 1):
     n = len(jax.devices())
     if n % model:
         raise ValueError(f"model={model} does not divide {n} devices")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_local_mesh(*, model: int = 1):
@@ -110,7 +119,7 @@ def make_local_mesh(*, model: int = 1):
         raise ValueError(f"model={model} does not divide {len(local)} "
                          "local devices")
     return Mesh(np.asarray(local).reshape(len(local) // model, model),
-                ("data", "model"))
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 # Hardware constants for the roofline terms (TPU v5e).

@@ -3,8 +3,13 @@
 This is the production entry point for the RL-CFD cells — the TPU-native
 equivalent of the paper's `relexi --config ...` SLURM job.  The scenario is
 selected by registry name (`repro.envs`); the fleet shards over the mesh's
-(pod, data) axes and the spec-built policy trains with clip-PPO using the
-paper's hyperparameters (Sec. 5.3).
+`data` axis (every device) and the spec-built policy trains with clip-PPO
+using the paper's hyperparameters (Sec. 5.3).
+
+A run that resumes from `--checkpoint-dir` continues to `--iterations`.
+The command exits non-zero when the run was not clean: an iteration was
+retried, a non-finite update was skipped, a return was non-finite, or no
+iteration ran (a checkpoint directory already at `--iterations`).
 
     # paper 24-DOF HIT configuration, 16 parallel environments:
     PYTHONPATH=src python -m repro.launch.rl_train --env hit_les_24dof \
@@ -19,16 +24,17 @@ from __future__ import annotations
 
 import argparse
 
-import jax
-
 from .. import envs
 from ..core.orchestrator import FleetConfig
 from ..core.ppo import PPOConfig
-from ..core.runner import Runner, RunnerConfig
+from ..core.runner import Runner, RunnerConfig, read_metrics, run_faults
 from . import mesh as mesh_lib
+from .compile_cache import enable_compile_cache
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> list[dict]:
+    """Parse `argv`, train, and return this run's per-iteration records;
+    raises SystemExit(1) when the run was not clean (module docstring)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--env", default=None, choices=envs.registered(),
                     help="registered environment name")
@@ -44,7 +50,8 @@ def main() -> None:
     ap.add_argument("--checkpoint-dir", default="checkpoints/relexi")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-mesh", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.env:
         name = args.env
@@ -54,7 +61,7 @@ def main() -> None:
         name = f"hit_les_{args.dof}dof"
     env = envs.make(name)
 
-    mesh = None if args.no_mesh else mesh_lib.make_host_mesh()
+    mesh = None if args.no_mesh else mesh_lib.make_fleet_mesh()
     fleet = FleetConfig(n_envs=args.n_envs,
                         bank_size=max(args.n_envs + 1, 9))
     runner = Runner(
@@ -70,10 +77,18 @@ def main() -> None:
         mesh=mesh,
     )
     print(f"training {name}: {args.iterations} iterations x {args.n_envs} envs")
-    history = runner.train()
+    n_logged = len(read_metrics(runner.metrics_path))
+    runner.restore()
+    expected = max(1, args.iterations - runner.iteration)
+    history = runner.train(resume=False)
     last = history[-1] if history else {}
     print(f"finished {len(history)} iterations; "
           f"final return={last.get('return_norm', float('nan')):.4f}")
+    faults = run_faults(read_metrics(runner.metrics_path)[n_logged:],
+                        expected)
+    if faults:
+        raise SystemExit("training run not clean: " + "; ".join(faults))
+    return history
 
 
 if __name__ == "__main__":

@@ -314,12 +314,11 @@ def _flash_decode(q, k_cache, v_cache, k_new, v_new, pos, slot, is_window,
         den = jax.lax.psum(l * w, axis)
         return num / jnp.maximum(den, 1e-30)[..., None], kc, vc
 
-    from jax.experimental.shard_map import shard_map
     spec_kv = P(b_spec, None, axis, None)
     spec_tok = P(b_spec, None, None, None)
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(spec_tok, spec_kv, spec_kv, spec_tok, spec_tok, P(), P()),
         out_specs=(spec_tok, spec_kv, spec_kv),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, k_new, v_new, pos, slot)

@@ -44,21 +44,6 @@ DEFAULT_RULES: dict[str, Any] = {
 }
 
 
-def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """Version-agnostic `jax.sharding.AbstractMesh` constructor.
-
-    jax changed the signature from a single `((name, size), ...)` tuple to
-    separate `(axis_sizes, axis_names)` arguments; divisibility logic here
-    only ever needs `mesh.shape`, so accept the modern spelling and build
-    whichever the installed jax expects.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:  # older jax: AbstractMesh(shape_tuple)
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
-
-
 class AxisRules:
     def __init__(self, mesh: Mesh | None, rules: dict[str, Any] | None = None):
         self.mesh = mesh

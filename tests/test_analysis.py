@@ -138,9 +138,7 @@ def _audit(fn, args, **built_kw):
 
 
 def test_jax001_f64_promotion():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         findings = _audit(lambda u: u.astype(jnp.float64) * 2.0,  # repro-lint: disable=AST004 -- deliberate f64 red-team fixture
                           (jnp.zeros((4,), jnp.float32),))
     assert "JAX001" in _rules(findings)

@@ -12,6 +12,7 @@ from repro.configs import relexi_hit
 from repro.core import checkpoints
 from repro.core.orchestrator import FleetConfig
 from repro.core.runner import Runner, RunnerConfig
+from repro.launch import mesh as mesh_lib
 
 
 def _tree():
@@ -61,7 +62,7 @@ def test_restore_with_shardings(tmp_path):
     d = str(tmp_path / "ck")
     tree = _tree()
     checkpoints.save(d, 0, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = mesh_lib.auto_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
     got, _ = checkpoints.restore(d, 0, tree, shardings=sh)
     assert got["a"].sharding == NamedSharding(mesh, P())
@@ -120,7 +121,7 @@ def test_runner_resume_deterministic(tmp_path):
 
 def test_elastic_fleet_resize():
     from repro.core import elastic
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = mesh_lib.auto_mesh((1,), ("data",))
     assert elastic.elastic_fleet(16, mesh) == 16
     assert elastic.elastic_fleet(16, None) == 16
 

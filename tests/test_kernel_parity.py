@@ -93,7 +93,8 @@ def test_fused_rhs_parity(prefix, n_poly, n_elem, block_e, dtype):
 
 def test_fused_rhs_oracle_matches_solver_assembly():
     """The self-contained `ref.navier_stokes_rhs_fused` oracle reproduces the
-    stage-by-stage solver assembly bit-for-bit (same ops, same order) — the
+    stage-by-stage solver assembly to float32 rounding (same ops; its
+    planar layout sums the node-axis contractions offset by offset) — the
     anchor that ties the mega-kernel's parity gate back to the physics."""
     from repro.cfd import initial
 

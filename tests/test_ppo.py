@@ -6,6 +6,7 @@ import pytest
 
 from repro import optim
 from repro.core import policy as policy_lib, ppo
+from repro.launch import mesh as mesh_lib
 
 
 def _traj(rewards, values, last_value, dones=None):
@@ -142,8 +143,7 @@ def test_compressed_psum_int8_error_feedback():
     """int8 psum with error feedback: the residual carries the quantization
     error so the running sum stays unbiased."""
     from repro.core import compression
-    mesh = jax.make_mesh((1,), ("pod",))
-    from jax.experimental.shard_map import shard_map
+    mesh = mesh_lib.auto_mesh((1,), ("pod",))
     from jax.sharding import PartitionSpec as P
 
     g = {"w": jnp.linspace(-1.0, 1.0, 16)}
@@ -152,7 +152,7 @@ def test_compressed_psum_int8_error_feedback():
         red, err = compression.compressed_psum(x, "pod", method="int8")
         return red, err
 
-    red, err = shard_map(f, mesh=mesh, in_specs=({"w": P()},),
+    red, err = jax.shard_map(f, mesh=mesh, in_specs=({"w": P()},),
                          out_specs=({"w": P()}, {"w": P()}))(g)
     np.testing.assert_allclose(np.asarray(red["w"] + err["w"]),
                                np.asarray(g["w"]), atol=1e-6)

@@ -13,6 +13,7 @@ from repro.cfd import spectra
 from repro.core import ppo
 from repro.kernels import ref
 from repro.parallel import sharding as shd
+from repro.launch import mesh as mesh_lib
 
 _settings = settings(max_examples=25, deadline=None)
 
@@ -54,7 +55,7 @@ def test_gae_of_zero_rewards_zero_values_is_zero(t, b, gamma, lam):
 @_settings
 @given(st.integers(1, 32), st.integers(1, 17), st.integers(1, 8))
 def test_logical_to_spec_never_breaks_divisibility(d0, d1, d2):
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = mesh_lib.auto_mesh((1,), ("model",))
     rules = shd.AxisRules(mesh, {"a": "model", "b": "model", "c": None})
     spec = shd.logical_to_spec((d0, d1, d2), ("a", "b", "c"), rules)
     assert len(spec) == 3
@@ -64,7 +65,7 @@ def test_logical_to_spec_never_breaks_divisibility(d0, d1, d2):
 
 
 def test_logical_to_spec_drops_consumed_axes():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = mesh_lib.auto_mesh((1,), ("model",))
     rules = shd.AxisRules(mesh, {"a": "model", "b": "model"})
     spec = shd.logical_to_spec((4, 4), ("a", "b"), rules)
     # the second dim must not reuse the axis the first consumed

@@ -12,6 +12,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.models import api, attention
 from repro.parallel import sharding as shd
+from repro.launch import mesh as mesh_lib
 
 
 def test_default_rules_cover_model_axes():
@@ -27,7 +28,7 @@ def test_constrain_noop_without_context():
 
 
 def test_constrain_applies_spec_on_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = mesh_lib.auto_mesh((1, 1), ("data", "model"))
     with mesh, shd.axis_rules(mesh):
         y = jax.jit(lambda x: shd.constrain(x, "batch", "mlp"))(
             jnp.ones((4, 8)))
@@ -35,7 +36,7 @@ def test_constrain_applies_spec_on_mesh():
 
 
 def test_param_specs_2d_weight():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = mesh_lib.auto_mesh((1, 1), ("data", "model"))
     rules = shd.AxisRules(mesh)
     params = {"w": jnp.ones((8, 16))}
     axes = {"w": ("embed", "mlp")}
@@ -45,7 +46,7 @@ def test_param_specs_2d_weight():
 
 def test_param_specs_nondivisible_falls_back():
     # AbstractMesh: divisibility logic only needs mesh.shape
-    mesh = shd.abstract_mesh((1, 2), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
     rules = shd.AxisRules(mesh)
     specs = shd.param_specs({"w": jnp.ones((8, 25))}, {"w": ("embed", "heads")},
                             rules)
@@ -69,7 +70,7 @@ def test_flash_decode_combine_matches_oracle():
     cache = {"k": kx.at[:, :, 5:].set(0), "v": vx.at[:, :, 5:].set(0),
              "pos": jnp.asarray(5, jnp.int32)}
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = mesh_lib.auto_mesh((1, 1), ("data", "model"))
     with mesh, shd.axis_rules(mesh):
         out_flash, c1 = jax.jit(
             lambda p, x, c: attention.decode_attention(
@@ -89,11 +90,10 @@ def test_lower_cell_on_host_mesh():
     from repro.launch import specs
     cfg = configs.get_reduced("h2o-danube-1.8b")
     shape = ShapeConfig("tiny_train", 64, 4, "train")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = mesh_lib.auto_mesh((1, 1), ("data", "model"))
     lowered, meta = specs.lower_cell(cfg, shape, mesh)
     compiled = lowered.compile()
-    from repro.launch.hlo_analysis import cost_analysis_dict
-    assert cost_analysis_dict(compiled)["flops"] > 0
+    assert compiled.cost_analysis()["flops"] > 0
     shape_d = ShapeConfig("tiny_decode", 64, 4, "decode")
     lowered, _ = specs.lower_cell(cfg, shape_d, mesh)
     assert lowered.compile() is not None
@@ -102,7 +102,7 @@ def test_lower_cell_on_host_mesh():
 def test_orchestrator_sharded_fleet():
     from repro.configs import relexi_hit
     from repro.core.orchestrator import FleetConfig, Orchestrator
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = mesh_lib.auto_mesh((1,), ("data",))
     orch = Orchestrator(relexi_hit.reduced(), FleetConfig(n_envs=2, bank_size=3),
                         mesh=mesh)
     traj = orch.sample_fleet(orch.params_placeholder, jax.random.PRNGKey(0)) \
@@ -135,3 +135,48 @@ def test_roofline_terms_math():
         n_chips=1, peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
     assert t["bound"] == "compute"
     assert t["roofline_fraction"] == pytest.approx(1.0)
+
+
+_FOUR_DEVICE_WORKER = r"""
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=4")
+import jax
+from repro.fleet import make_fleet_runner, scheduler
+from repro.fleet.pipeline import FleetRunnerConfig
+from repro.launch import mesh as mesh_lib
+
+mesh = mesh_lib.make_fleet_mesh()
+assert dict(mesh.shape) == {"data": 4, "model": 1}, mesh.shape
+runner = make_fleet_runner(
+    ("hit_les_reduced",), total_envs=64, mesh=mesh,
+    run_cfg=FleetRunnerConfig(checkpoint_dir="unused", bank_size=4),
+    use_artifacts=False)
+keys = {"hit_les_reduced": scheduler.rollout_key(runner.seed_key, 0, 0)}
+padded = jax.jit(runner.program.rollout_super_batch)(runner.params, keys)
+rewards = padded["hit_les_reduced"].rewards
+shards = sorted((s.device.id, s.data.shape[1])
+                for s in rewards.addressable_shards)
+assert shards == [(d, 16) for d in range(4)], shards
+print("four devices ok")
+"""
+
+
+def test_fleet_mesh_places_16_envs_per_device_on_four_devices():
+    """`make_fleet_mesh` puts all four devices on `data`, and the fleet's
+    64-env rollout gives each device 16 envs (CPU virtual devices, fresh
+    subprocess: the device count is fixed at backend start-up)."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_WORKER],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "four devices ok" in proc.stdout
