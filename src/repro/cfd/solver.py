@@ -301,6 +301,7 @@ def navier_stokes_rhs(
     return rhs + rhs_forcing(u, vel, cfg)
 
 
+@jax.named_scope("solver.rk_substep")   # repro.obs: its ops' scope
 def rk_substep(u: jax.Array, cs_nodes: jax.Array, cfg: HITConfig, ops: dict) -> jax.Array:
     """One low-storage RK5(4) step of size cfg.dt."""
     dt = jnp.asarray(cfg.dt, dtype=u.dtype)

@@ -50,7 +50,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from .. import optim
+from .. import obs, optim
 from ..core import ppo as ppo_lib
 from ..core.orchestrator import FleetConfig, Orchestrator
 from ..core.runner import RunnerBase, RunnerConfig
@@ -234,19 +234,22 @@ class FleetRunner(RunnerBase):
         params_{k+1} without waiting on rollout k+1 — the next rollout is
         always the computation left in flight when the host runs ahead
         (steady-state double buffering).
+
+        Either way the host work is the span `fleet.dispatch` (repro.obs).
         """
-        if self.program is not None:
-            self.params, self.opt_state, self.broker = self.program.step(
-                self.params, self.opt_state, self.broker,
-                jnp.asarray(k, jnp.int32), self._keys(k + 1))
-            return
-        params_k = self.params
-        trajs_k = {name: broker_lib.latest_traj(self.broker, name)
-                   for name in self.forch.names}
-        self.params, self.opt_state, stats = self._update(
-            params_k, self.opt_state, trajs_k, jnp.asarray(k, jnp.int32))
-        next_trajs = self.forch.sample_all(params_k, self._keys(k + 1))
-        self._push_all(next_trajs, stats)
+        with obs.span("fleet.dispatch"):
+            if self.program is not None:
+                self.params, self.opt_state, self.broker = self.program.step(
+                    self.params, self.opt_state, self.broker,
+                    jnp.asarray(k, jnp.int32), self._keys(k + 1))
+                return
+            params_k = self.params
+            trajs_k = {name: broker_lib.latest_traj(self.broker, name)
+                       for name in self.forch.names}
+            self.params, self.opt_state, stats = self._update(
+                params_k, self.opt_state, trajs_k, jnp.asarray(k, jnp.int32))
+            next_trajs = self.forch.sample_all(params_k, self._keys(k + 1))
+            self._push_all(next_trajs, stats)
 
     def run_iteration_sync(self, k: int) -> dict:
         """Paper-synchronous iteration: sample -> block -> update -> block,

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import obs as obs_lib
 from ..core import policy as policy_lib
 from ..core import ppo as ppo_lib
 from ..envs.base import EnvState
@@ -146,6 +147,7 @@ class FleetProgram:
         # kept undonated to match the dispatch path's audit expectations.
         self._step = jax.jit(self._step_impl, donate_argnums=(1, 2))
         self._prologue = jax.jit(self._prologue_impl, donate_argnums=(1,))
+        self._registered = False
 
     @property
     def names(self) -> tuple[str, ...]:
@@ -192,16 +194,19 @@ class FleetProgram:
 
         def step_fn(state: EnvState, noise_t: jax.Array):
             obs = env.observe(state)
-            mean, std = pol.dist(params, obs)
-            action = mean + std * noise_t
-            logp = policy_lib.log_prob(mean, std, action)
-            val = pol.value(params, obs)
+            with jax.named_scope("rollout.policy"):
+                mean, std = pol.dist(params, obs)
+                action = mean + std * noise_t
+                logp = policy_lib.log_prob(mean, std, action)
+                val = pol.value(params, obs)
             res = env.step(state, action)
             return res.state, (obs, action, logp, res.reward, res.done, val)
 
         final_state, (obs, actions, log_probs, rewards, dones, values) = \
             jax.lax.scan(step_fn, state0, noise)
-        last_value = pol.value(params, env.observe(final_state))
+        obs_last = env.observe(final_state)
+        with jax.named_scope("rollout.policy"):
+            last_value = pol.value(params, obs_last)
         return ppo_lib.Trajectory(obs=obs, actions=actions,
                                   log_probs=log_probs, rewards=rewards,
                                   dones=dones, values=values,
@@ -218,6 +223,7 @@ class FleetProgram:
                                          noises[name])
                 for name in self.names}
 
+    @jax.named_scope("fleet.rollout")
     def rollout_super_batch(self, params: dict, keys: dict[str, jax.Array]
                             ) -> dict[str, ppo_lib.Trajectory]:
         """One rollout pass over the whole fleet; returns PADDED
@@ -237,30 +243,40 @@ class FleetProgram:
         return fn(params, u0s, noises)
 
     # --- the compiled iteration ----------------------------------------------
+    # named scopes (repro.obs) mark each part's ops for the device trace
+    def _park(self, broker, padded):
+        with jax.named_scope("fleet.broker"):
+            for n in self.names:
+                broker = broker_lib.push_traj(
+                    broker, n, slice_traj(padded[n], self.n_envs[n]))
+        return broker
+
     def _step_impl(self, params, opt_state, broker, k, keys):
-        trajs_k = {n: broker_lib.latest_traj(broker, n) for n in self.names}
-        new_params, new_opt, stats = guarded_fleet_update(
-            params, opt_state, self.ppo_cfg, self.mcfg, trajs_k,
-            self.weights, k)
-        padded = self.rollout_super_batch(params, keys)
-        for n in self.names:
-            broker = broker_lib.push_traj(
-                broker, n, slice_traj(padded[n], self.n_envs[n]))
-        broker = broker_lib.push_metrics(broker, "fleet", stats)
+        with jax.named_scope("fleet.broker"):
+            trajs_k = {n: broker_lib.latest_traj(broker, n)
+                       for n in self.names}
+        with jax.named_scope("fleet.update"):
+            new_params, new_opt, stats = guarded_fleet_update(
+                params, opt_state, self.ppo_cfg, self.mcfg, trajs_k,
+                self.weights, k)
+        broker = self._park(broker, self.rollout_super_batch(params, keys))
+        with jax.named_scope("fleet.broker"):
+            broker = broker_lib.push_metrics(broker, "fleet", stats)
         return new_params, new_opt, broker
 
     def _prologue_impl(self, params, broker, keys):
         """Iteration-0 priming: rollout + park, no update (the broker must
         hold traj_0 before the first in-program update can consume it)."""
-        padded = self.rollout_super_batch(params, keys)
-        for n in self.names:
-            broker = broker_lib.push_traj(
-                broker, n, slice_traj(padded[n], self.n_envs[n]))
-        return broker
+        return self._park(broker, self.rollout_super_batch(params, keys))
 
     def step(self, params, opt_state, broker, k, keys):
         """Dispatch iteration k: update k + rollout k+1 + broker pushes,
-        one XLA program.  `opt_state` and `broker` are DONATED."""
+        one XLA program.  `opt_state` and `broker` are DONATED.  The first
+        dispatch registers the program for `repro.obs.op_scopes()`."""
+        if not self._registered:
+            obs_lib.register_program("fleet.step", self._step,
+                                     (params, opt_state, broker, k, keys))
+            self._registered = True
         return self._step(params, opt_state, broker, k, keys)
 
     def prologue(self, params, broker, keys):

@@ -105,21 +105,27 @@ def fused_navier_stokes_rhs(
     """
     mesh = u.shape[-7:]
     k, n = mesh[0], mesh[3]
-    ub = u.reshape((-1,) + mesh)
-    csb = cs_nodes.reshape((-1,) + mesh[:-1] + (1,))
-    b = ub.shape[0]
+    b = math.prod(u.shape[:-7])
     block_e = envs_per_block(b, k, block_e)
     pad = (-b) % block_e
-    if pad:
-        # pad with copies of the first mesh: every padded lane is a valid
-        # flow state, so no inf/nan can leak out of the discarded blocks
-        ub = jnp.concatenate(
-            [ub, jnp.broadcast_to(ub[:1], (pad,) + mesh)], axis=0)
-        csb = jnp.concatenate(
-            [csb, jnp.broadcast_to(csb[:1], (pad,) + csb.shape[1:])], axis=0)
     bp = b + pad
     p, lanes = n**3, block_e * k**3
-    consts = ref.planar_consts(d_matrix, w, n, k, block_e)
+    # everything but the kernel is layout work: named scope `rhs.layout`
+    # (repro.obs), so the device trace tells it from the kernel
+    with jax.named_scope("rhs.layout"):
+        ub = u.reshape((-1,) + mesh)
+        csb = cs_nodes.reshape((-1,) + mesh[:-1] + (1,))
+        if pad:
+            # pad with copies of the first mesh: every padded lane is a
+            # valid flow state, so no inf/nan can leak out of the discarded
+            # blocks
+            ub = jnp.concatenate(
+                [ub, jnp.broadcast_to(ub[:1], (pad,) + mesh)], axis=0)
+            csb = jnp.concatenate(
+                [csb, jnp.broadcast_to(csb[:1], (pad,) + csb.shape[1:])],
+                axis=0)
+        planar = (ref.to_planar(ub), ref.to_planar(csb)[0],
+                  *ref.planar_consts(d_matrix, w, n, k, block_e))
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_kernel, n=n, k=k, inv_w_end=inv_w_end, jac=jac,
@@ -139,5 +145,6 @@ def fused_navier_stokes_rhs(
             vmem_limit_bytes=vmem_limit_bytes(n, lanes)),
         interpret=resolve_interpret(interpret),
         name="fused_ns_rhs",
-    )(ref.to_planar(ub), ref.to_planar(csb)[0], *consts)
-    return ref.from_planar(out, mesh)[:b].reshape(u.shape)
+    )(*planar)
+    with jax.named_scope("rhs.layout"):
+        return ref.from_planar(out, mesh)[:b].reshape(u.shape)

@@ -24,11 +24,9 @@ deployment framing).  Three layers:
                 bit-identical to `Orchestrator.evaluate`'s at fp32) with a
                 donated on-device request-counter buffer.
 
-`benchmarks/perf_serve.py` publishes the p50/p99 latency + throughput
-ladder (`perf_serve.json`), compile-certified under the trace auditor,
-and the `serve_step` entry point is registered in
-`analysis/entrypoints.py` so repro-lint gates its donation/f64
-invariants.
+The `serve_step` entry point is registered in `analysis/entrypoints.py`
+so repro-lint gates its donation/f64 invariants; the benchmark's
+open-loop driver (`bench/drivers/serve.py`) measures its latency.
 """
 from .batcher import (DEFAULT_BUCKETS, PendingBatch, RequestBatcher,
                       bucket_for)

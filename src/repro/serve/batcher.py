@@ -32,7 +32,7 @@ import numpy as np
 
 # Powers of two up to 16: small enough that the whole ladder compiles in
 # seconds at reduced shapes, doubling so any pending count wastes < half a
-# batch of padding.  Callers tune per deployment (perf_serve.py sweeps it).
+# batch of padding.  Callers tune per deployment.
 DEFAULT_BUCKETS = (1, 2, 4, 8, 16)
 
 
