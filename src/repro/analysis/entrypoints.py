@@ -2,7 +2,8 @@
 
 These are the programs whose compiled form IS the product — the per-step
 solver advance, the fleet rollout, the PPO/fleet updates, the fused RHS
-mega-kernel, and the broker's donated push.  `jaxpr_audit.audit_entry`
+mega-kernel (natural-layout wrapper and the planar entry the RK loop
+calls), and the broker's donated push.  `jaxpr_audit.audit_entry`
 traces each one at a reduced (but structurally faithful) shape and checks
 the resulting jaxpr/StableHLO against the compiled-program invariants; the
 trace auditor re-drives a subset through a reduced training run and pins
@@ -227,6 +228,28 @@ def _build_fused_rhs() -> Built:
         args=(u, cs))
 
 
+def _build_fused_rhs_planar() -> Built:
+    import jax
+    import jax.numpy as jnp
+
+    from ..cfd import initial
+    from ..kernels import rhs as rhs_mod
+
+    cfg = _hit_cfg()
+    ops_d = cfg.operators()
+    u = initial.sample_initial_state(jax.random.PRNGKey(0), cfg)
+    u_pl, cs_pl, block_e = rhs_mod.to_planar_batch(
+        u, jnp.full(u.shape[:-1], 0.17, u.dtype))
+    return Built(
+        fn=lambda u, cs: rhs_mod.fused_navier_stokes_rhs_planar(
+            u, cs, ops_d["D"], ops_d["w"], k=cfg.n_elem, block_e=block_e,
+            inv_w_end=ops_d["inv_w_end"], jac=cfg.dg.jac,
+            delta=cfg.delta_filter, mu=cfg.gas.mu, prandtl=cfg.prandtl,
+            prandtl_turb=cfg.prandtl_turb, forcing_a0=cfg.forcing_a0,
+            k_tke=cfg.k_tke, interpret=True),
+        args=(u_pl, cs_pl))
+
+
 def _build_serve_step() -> Built:
     import jax
     import jax.numpy as jnp
@@ -267,6 +290,7 @@ ENTRYPOINTS: tuple[EntryPoint, ...] = (
     EntryPoint("fleet_program", _build_fleet_program),
     EntryPoint("broker_push", _build_broker_push),
     EntryPoint("fused_rhs", _build_fused_rhs),
+    EntryPoint("fused_rhs_planar", _build_fused_rhs_planar),
     EntryPoint("serve_step", _build_serve_step),
 )
 

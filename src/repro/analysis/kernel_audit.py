@@ -103,6 +103,20 @@ def _kernel_cases() -> dict[str, Callable[[], tuple]]:
             prandtl_turb=cfg.prandtl_turb, forcing_a0=cfg.forcing_a0,
             k_tke=cfg.k_tke, interpret=True)
 
+    def fused_rhs_planar():
+        from ..cfd.solver import HITConfig
+        from ..kernels.rhs import fused_navier_stokes_rhs_planar as fn
+        cfg = HITConfig(n_poly=3, n_elem=2, use_kernels=False)
+        ops = cfg.operators()
+        # 32 meshes of 2^3 elements: two 128-lane blocks of 16 meshes
+        u = jnp.zeros((5, 64, 256), jnp.float32)
+        cs = jnp.zeros((64, 256), jnp.float32)
+        return fn, (u, cs, ops["D"], ops["w"]), dict(
+            k=2, block_e=16, inv_w_end=ops["inv_w_end"], jac=cfg.dg.jac,
+            delta=cfg.delta_filter, mu=cfg.gas.mu, prandtl=cfg.prandtl,
+            prandtl_turb=cfg.prandtl_turb, forcing_a0=cfg.forcing_a0,
+            k_tke=cfg.k_tke, interpret=True)
+
     def flash_attention():
         from ..kernels.flash_attention import flash_attention as fn
         q = jnp.zeros((1, 2, 64, 16), jnp.float32)
@@ -121,6 +135,7 @@ def _kernel_cases() -> dict[str, Callable[[], tuple]]:
         "smagorinsky_nut": smagorinsky_nut,
         "wall_model_tau": wall_model_tau,
         "fused_rhs": fused_rhs,
+        "fused_rhs_planar": fused_rhs_planar,
         "flash_attention": flash_attention,
         "linear_scan": linear_scan,
     }

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -287,10 +288,8 @@ def navier_stokes_rhs(
         from ..kernels import ops as kops
 
         return kops.navier_stokes_rhs_fused(
-            u, cs_nodes, ops["D"], ops["w"], inv_w_end=ops["inv_w_end"],
-            jac=cfg.dg.jac, delta=cfg.delta_filter, mu=cfg.gas.mu,
-            prandtl=cfg.prandtl, prandtl_turb=cfg.prandtl_turb,
-            forcing_a0=cfg.forcing_a0, k_tke=cfg.k_tke, impl="kernel")
+            u, cs_nodes, ops["D"], ops["w"], impl="kernel",
+            **_fused_rhs_scalars(cfg, ops))
 
     rho, vel, p, temp = equations.conservative_to_primitive(u)
     e_spec = u[..., 4] / rho
@@ -301,10 +300,21 @@ def navier_stokes_rhs(
     return rhs + rhs_forcing(u, vel, cfg)
 
 
+def _fused_rhs_scalars(cfg: HITConfig, ops: dict) -> dict:
+    """The static scalars of the fused RHS kernel (kernels/rhs.py)."""
+    return dict(inv_w_end=ops["inv_w_end"], jac=cfg.dg.jac,
+                delta=cfg.delta_filter, mu=cfg.gas.mu, prandtl=cfg.prandtl,
+                prandtl_turb=cfg.prandtl_turb, forcing_a0=cfg.forcing_a0,
+                k_tke=cfg.k_tke)
+
+
 @jax.named_scope("solver.rk_substep")   # repro.obs: its ops' scope
-def rk_substep(u: jax.Array, cs_nodes: jax.Array, cfg: HITConfig, ops: dict) -> jax.Array:
-    """One low-storage RK5(4) step of size cfg.dt."""
-    dt = jnp.asarray(cfg.dt, dtype=u.dtype)
+def rk_substep(u: jax.Array, rhs: Callable[[jax.Array], jax.Array],
+               dt: float) -> jax.Array:
+    """One low-storage RK5(4) step of size dt.  `rhs(u)` is the
+    semi-discrete RHS in u's layout: the stage arithmetic is elementwise,
+    so one function serves the natural and the planar carry."""
+    dt = jnp.asarray(dt, dtype=u.dtype)
     du = jnp.zeros_like(u)
     for stage in range(5):
         # the cast keeps the carry in the rollout compute dtype: the jnp RHS
@@ -312,8 +322,8 @@ def rk_substep(u: jax.Array, cs_nodes: jax.Array, cfg: HITConfig, ops: dict) -> 
         # the fused kernel already returns u.dtype — both are no-ops in the
         # default fp32 path.  RK constants go through float() so the weak
         # python scalar cannot re-promote a bf16 carry.
-        rhs = navier_stokes_rhs(u, cs_nodes, cfg, ops).astype(u.dtype)
-        du = float(_RK_A[stage]) * du + dt * rhs
+        r = rhs(u).astype(u.dtype)
+        du = float(_RK_A[stage]) * du + dt * r
         u = u + float(_RK_B[stage]) * du
     return u
 
@@ -323,6 +333,14 @@ def advance_rl_interval(u: jax.Array, cs_elem: jax.Array, cfg: HITConfig) -> jax
     """Advance the LES by Delta t_RL under fixed per-element C_s (one MDP
     transition).  This is the unit of work the paper distributes over MPI
     ranks; here it is one XLA program.
+
+    With `cfg.kernels_enabled` the RK carry stays in the fused kernel's
+    planar layout (kernels/rhs.py) for the whole interval: the state and
+    the nodal C_s are converted once before the substep scan
+    (`to_planar_batch`, batch padded to whole kernel blocks) and back once
+    after it (`from_planar_batch`), both under the `rhs.layout` scope, and
+    every RK stage calls the planar kernel entry directly.  Otherwise the
+    staged jnp RHS steps the natural layout — the oracle.
 
     With `cfg.precision == "bf16"` the state is advanced in bfloat16 for
     the whole interval (the mixed-precision rollout) and cast back to
@@ -339,8 +357,26 @@ def advance_rl_interval(u: jax.Array, cs_elem: jax.Array, cfg: HITConfig) -> jax
         # substep — the churn JAX002 guards against)
         ops = dict(ops, D=ops["D"].astype(dtype), w=ops["w"].astype(dtype))
 
-    def body(u, _):
-        return rk_substep(u, cs_nodes, cfg, ops), None
+    if cfg.kernels_enabled:
+        from ..kernels import ops as kops
+        from ..kernels.rhs import from_planar_batch, to_planar_batch
 
-    u, _ = jax.lax.scan(body, u, None, length=cfg.n_substeps)
-    return u.astype(jnp.float32)
+        x, cs_pl, block_e = to_planar_batch(u, cs_nodes)
+
+        def rhs(x):
+            return kops.navier_stokes_rhs_planar(
+                x, cs_pl, ops["D"], ops["w"], k=cfg.n_elem, block_e=block_e,
+                impl="kernel", **_fused_rhs_scalars(cfg, ops))
+    else:
+        x = u
+
+        def rhs(x):
+            return navier_stokes_rhs(x, cs_nodes, cfg, ops)
+
+    def body(x, _):
+        return rk_substep(x, rhs, cfg.dt), None
+
+    x, _ = jax.lax.scan(body, x, None, length=cfg.n_substeps)
+    if cfg.kernels_enabled:
+        x = from_planar_batch(x, u.shape)
+    return x.astype(jnp.float32)

@@ -27,6 +27,7 @@ from .flash_attention import flash_attention as _fa_pallas
 from .linear_scan import linear_scan as _ls_pallas
 from .policy import default_impl
 from .rhs import fused_navier_stokes_rhs as _rhs_pallas
+from .rhs import fused_navier_stokes_rhs_planar as _rhs_planar_pallas
 from .smagorinsky import smagorinsky_nut as _smag_pallas
 from .wall_model import wall_model_tau as _wm_pallas
 
@@ -64,6 +65,24 @@ def navier_stokes_rhs_fused(u: jax.Array, cs_nodes: jax.Array,
     if (impl or default_impl()) == "kernel":
         return _rhs_pallas(u, cs_nodes, d_matrix, w, block_e=block_e, **kw)
     return ref.navier_stokes_rhs_fused(u, cs_nodes, d_matrix, w, **kw)
+
+
+def navier_stokes_rhs_planar(u_pl: jax.Array, cs_pl: jax.Array,
+                             d_matrix: jax.Array, w: jax.Array, *, k: int,
+                             block_e: int, impl: str | None = None,
+                             **kw) -> jax.Array:
+    """The fused RHS on planar operands (kernels/rhs.to_planar_batch's
+    layout) — the op `cfd/solver.advance_rl_interval` steps its planar RK
+    carry with when kernels are enabled.  `kw`: the scalars of
+    `navier_stokes_rhs_fused`."""
+    if (impl or default_impl()) == "kernel":
+        return _rhs_planar_pallas(u_pl, cs_pl, d_matrix, w, k=k,
+                                  block_e=block_e, **kw)
+    n = d_matrix.shape[0]
+    consts = ref.planar_consts(d_matrix, w, n, k, u_pl.shape[-1] // k**3)
+    planes = ref.navier_stokes_rhs_planar(u_pl, cs_pl, consts, n=n, k=k,
+                                          **kw)
+    return jnp.stack(planes).astype(u_pl.dtype)
 
 
 # --- wall model --------------------------------------------------------------

@@ -13,6 +13,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 
 
@@ -144,10 +145,23 @@ def from_planar(x: jax.Array, mesh: tuple[int, ...]) -> jax.Array:
     return jnp.transpose(x, (4, 5, 6, 7, 1, 2, 3, 0))
 
 
-def _node_index(n: int) -> list[jax.Array]:
-    """Node index along each node axis of every planar row."""
-    rows = jnp.arange(n**3)
+def _node_index(n: int) -> list[np.ndarray]:
+    """Node index along each node axis of every planar row (host table)."""
+    rows = np.arange(n**3)
     return [rows // n ** (2 - d) % n for d in range(3)]
+
+
+def _deriv_index(n: int) -> list[tuple[np.ndarray, ...]]:
+    """Host tables of `deriv_coef`, per node axis: the row's node index and
+    its partner's i_d + o (clipped), and whether the partner is inside the
+    element."""
+    offsets = np.arange(-(n - 1), n)[:, None]
+    out = []
+    for node in _node_index(n):
+        m = node[None, :] + offsets
+        out.append((np.broadcast_to(node, m.shape), np.clip(m, 0, n - 1),
+                    (m >= 0) & (m < n)))
+    return out
 
 
 def deriv_coef(d_matrix: jax.Array, n: int) -> jax.Array:
@@ -155,12 +169,8 @@ def deriv_coef(d_matrix: jax.Array, n: int) -> jax.Array:
     o = -(n-1)..n-1 of every planar row, zero where i_d + o is outside the
     element."""
     d32 = d_matrix.astype(jnp.float32)
-    offsets = jnp.arange(-(n - 1), n)[:, None]
-    coef = []
-    for node in _node_index(n):
-        m = node[None, :] + offsets
-        vals = d32[jnp.broadcast_to(node, m.shape), jnp.clip(m, 0, n - 1)]
-        coef.append(jnp.where((m >= 0) & (m < n), vals, 0.0))
+    coef = [jnp.where(inside, d32[rows, cols], 0.0)
+            for rows, cols, inside in _deriv_index(n)]
     return jnp.stack(coef)[..., None]
 
 
@@ -176,23 +186,33 @@ def planar_deriv(x, coef, n: int, d: int, roll=jnp.roll):
     return out
 
 
+def _planar_masks(n: int, k: int, n_env: int) -> tuple[np.ndarray, ...]:
+    """Host tables of `planar_consts`: the float32 rmask, lmask, emask."""
+    node = _node_index(n)
+    rmask = np.stack([node[d] == e for d in range(3) for e in (0, n - 1)])
+    lanes = np.arange(n_env * k**3)
+    elem = [lanes // k ** (2 - d) % k for d in range(3)]
+    lmask = np.stack([elem[d] == e for d in range(3) for e in (0, k - 1)])
+    emask = lanes[None, :] // k**3 == np.arange(n_env)[:, None]
+    f32 = np.float32
+    return (rmask.astype(f32)[..., None], lmask.astype(f32)[:, None, :],
+            emask.astype(f32)[:, None, :])
+
+
 def planar_consts(d_matrix: jax.Array, w: jax.Array, n: int, k: int,
                   n_env: int) -> PlanarConsts:
-    """Constants for `n_env` periodic K^3-element meshes of n^3 nodes."""
-    f32 = jnp.float32
+    """Constants for `n_env` periodic K^3-element meshes of n^3 nodes.  The
+    masks and index tables are built on the host, so a compiled program
+    holds them as literals and computes nothing for them per call."""
     node = _node_index(n)
-    rmask = jnp.stack([node[d] == e for d in range(3) for e in (0, n - 1)])
-    lanes = jnp.arange(n_env * k**3)
-    elem = [lanes // k ** (2 - d) % k for d in range(3)]
-    lmask = jnp.stack([elem[d] == e for d in range(3) for e in (0, k - 1)])
-    emask = lanes[None, :] // k**3 == jnp.arange(n_env)[:, None]
-    w2 = w.astype(f32) * 0.5  # reference [-1,1] -> unit mass
+    rmask, lmask, emask = _planar_masks(n, k, n_env)
+    w2 = w.astype(jnp.float32) * 0.5  # reference [-1,1] -> unit mass
     wq = w2[node[0]] * w2[node[1]] * w2[node[2]]
     return PlanarConsts(
         coef=deriv_coef(d_matrix, n),
-        rmask=rmask.astype(f32)[..., None],
-        lmask=lmask.astype(f32)[:, None, :],
-        emask=emask.astype(f32)[:, None, :],
+        rmask=jnp.asarray(rmask),
+        lmask=jnp.asarray(lmask),
+        emask=jnp.asarray(emask),
         wq=wq[:, None])
 
 

@@ -13,8 +13,13 @@ block of rhs.
 Layout: the kernel works on the planar layout of kernels/ref.py — C planes
 of (P, L) = (n^3 node rows, batch x K^3 element lanes) — so both minor dims
 of every block are tile-dense (the natural (..., n, n, n, 5) layout ends in
-(6, 5) at 24 DOF, which pads ~40x to the (8, 128) tile).  The wrapper
-transposes the state into and out of that layout.  A grid step holds
+(6, 5) at 24 DOF, which pads ~40x to the (8, 128) tile).
+`fused_navier_stokes_rhs_planar` is the kernel on planar operands: the
+solver's RK loop (`cfd/solver.advance_rl_interval`) converts its state to
+the planar layout once per RL interval (`to_planar_batch`), steps it there
+and converts back once (`from_planar_batch`).  `fused_navier_stokes_rhs`
+is the natural-layout wrapper for single calls: it converts into and out
+of the planar layout around each call.  A grid step holds
 `block_e` WHOLE meshes, because the RHS is not element-local: the surface
 exchange couples neighbour elements (periodic) and the Lundgren forcing
 needs whole-box quadrature means — both stay in-kernel when the mesh is
@@ -77,6 +82,94 @@ def vmem_limit_bytes(n: int, lanes: int) -> int | None:
                              + (3 * (2 * n - 1) + 7) * column)
 
 
+@jax.named_scope("rhs.layout")   # repro.obs: its ops' scope
+def to_planar_batch(u: jax.Array, cs_nodes: jax.Array,
+                    block_e: int = 1) -> tuple[jax.Array, jax.Array, int]:
+    """Natural-layout operands -> planar operands of the fused RHS.
+
+    u: (..., K, K, K, n, n, n, 5); cs_nodes shaped like u[..., 0].  Returns
+    (u_pl (5, n^3, L), cs_pl (n^3, L), meshes per grid step), the batch
+    padded to a whole number of grid steps (`envs_per_block`) with copies
+    of the first mesh: every padded lane is a valid flow state, so no
+    inf/nan can come out of the discarded lanes.
+    """
+    mesh = u.shape[-7:]
+    ub = u.reshape((-1,) + mesh)
+    csb = cs_nodes.reshape((-1,) + mesh[:-1] + (1,))
+    block_e = envs_per_block(ub.shape[0], mesh[0], block_e)
+    pad = (-ub.shape[0]) % block_e
+    if pad:
+        ub = jnp.concatenate(
+            [ub, jnp.broadcast_to(ub[:1], (pad,) + mesh)], axis=0)
+        csb = jnp.concatenate(
+            [csb, jnp.broadcast_to(csb[:1], (pad,) + csb.shape[1:])], axis=0)
+    return ref.to_planar(ub), ref.to_planar(csb)[0], block_e
+
+
+@jax.named_scope("rhs.layout")   # repro.obs: its ops' scope
+def from_planar_batch(x: jax.Array, shape: tuple[int, ...]) -> jax.Array:
+    """Inverse of `to_planar_batch`: (C, n^3, L) planes -> the natural
+    `shape`, the padded meshes dropped."""
+    b = math.prod(shape[:-7])
+    return ref.from_planar(x, shape[-7:])[:b].reshape(shape)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "k", "block_e", "inv_w_end", "jac", "delta", "mu", "prandtl",
+    "prandtl_turb", "forcing_a0", "k_tke", "interpret"))
+def fused_navier_stokes_rhs_planar(
+    u_pl: jax.Array,
+    cs_pl: jax.Array,
+    d_matrix: jax.Array,
+    w: jax.Array,
+    *,
+    k: int,
+    block_e: int,
+    inv_w_end: tuple[float, float],
+    jac: float,
+    delta: float,
+    mu: float,
+    prandtl: float,
+    prandtl_turb: float,
+    forcing_a0: float,
+    k_tke: float,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Fused RHS on planar operands (`to_planar_batch`'s layout).
+
+    u_pl: (5, n^3, L) with L a multiple of the block's block_e K^3 lanes;
+    cs_pl: (n^3, L); d_matrix (n, n); w (n,) GLL weights; scalars as in
+    the oracle.  Returns the (5, n^3, L) RHS in u_pl's dtype.
+    """
+    n = d_matrix.shape[0]
+    p, lanes = n**3, block_e * k**3
+    if u_pl.shape != (5, p, u_pl.shape[-1]) or u_pl.shape[-1] % lanes:
+        raise ValueError(f"u_pl {u_pl.shape} is not (5, {p}, L) with L a "
+                         f"multiple of {lanes} lanes")
+    with jax.named_scope("rhs.layout"):
+        consts = ref.planar_consts(d_matrix, w, n, k, block_e)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_kernel, n=n, k=k, inv_w_end=inv_w_end, jac=jac,
+                          delta=delta, mu=mu, prandtl=prandtl,
+                          prandtl_turb=prandtl_turb, forcing_a0=forcing_a0,
+                          k_tke=k_tke),
+        grid=(u_pl.shape[-1] // lanes,),
+        in_specs=[
+            pl.BlockSpec((5, p, lanes), lambda i: (0, 0, i)),
+            pl.BlockSpec((p, lanes), lambda i: (0, i)),
+            whole, whole, whole, whole, whole,
+        ],
+        out_specs=pl.BlockSpec((5, p, lanes), lambda i: (0, 0, i)),
+        out_shape=jax.ShapeDtypeStruct(u_pl.shape, u_pl.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit_bytes(n, lanes)),
+        interpret=resolve_interpret(interpret),
+        name="fused_ns_rhs",
+    )(u_pl, cs_pl, *consts)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "inv_w_end", "jac", "delta", "mu", "prandtl", "prandtl_turb",
     "forcing_a0", "k_tke", "block_e", "interpret"))
@@ -102,49 +195,13 @@ def fused_navier_stokes_rhs(
     u: (..., K, K, K, n, n, n, 5); cs_nodes shaped like u[..., 0];
     d_matrix (n, n); w (n,) GLL weights; scalars as in the oracle.  Returns
     the RHS with u's shape and dtype.  Matches ref.navier_stokes_rhs_fused.
+    Everything but the kernel is layout work, under the named scope
+    `rhs.layout` (repro.obs), so the device trace tells it from the kernel.
     """
-    mesh = u.shape[-7:]
-    k, n = mesh[0], mesh[3]
-    b = math.prod(u.shape[:-7])
-    block_e = envs_per_block(b, k, block_e)
-    pad = (-b) % block_e
-    bp = b + pad
-    p, lanes = n**3, block_e * k**3
-    # everything but the kernel is layout work: named scope `rhs.layout`
-    # (repro.obs), so the device trace tells it from the kernel
-    with jax.named_scope("rhs.layout"):
-        ub = u.reshape((-1,) + mesh)
-        csb = cs_nodes.reshape((-1,) + mesh[:-1] + (1,))
-        if pad:
-            # pad with copies of the first mesh: every padded lane is a
-            # valid flow state, so no inf/nan can leak out of the discarded
-            # blocks
-            ub = jnp.concatenate(
-                [ub, jnp.broadcast_to(ub[:1], (pad,) + mesh)], axis=0)
-            csb = jnp.concatenate(
-                [csb, jnp.broadcast_to(csb[:1], (pad,) + csb.shape[1:])],
-                axis=0)
-        planar = (ref.to_planar(ub), ref.to_planar(csb)[0],
-                  *ref.planar_consts(d_matrix, w, n, k, block_e))
-    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(_kernel, n=n, k=k, inv_w_end=inv_w_end, jac=jac,
-                          delta=delta, mu=mu, prandtl=prandtl,
-                          prandtl_turb=prandtl_turb, forcing_a0=forcing_a0,
-                          k_tke=k_tke),
-        grid=(bp // block_e,),
-        in_specs=[
-            pl.BlockSpec((5, p, lanes), lambda i: (0, 0, i)),
-            pl.BlockSpec((p, lanes), lambda i: (0, i)),
-            whole, whole, whole, whole, whole,
-        ],
-        out_specs=pl.BlockSpec((5, p, lanes), lambda i: (0, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((5, p, bp * k**3), u.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=vmem_limit_bytes(n, lanes)),
-        interpret=resolve_interpret(interpret),
-        name="fused_ns_rhs",
-    )(*planar)
-    with jax.named_scope("rhs.layout"):
-        return ref.from_planar(out, mesh)[:b].reshape(u.shape)
+    u_pl, cs_pl, block_e = to_planar_batch(u, cs_nodes, block_e)
+    out = fused_navier_stokes_rhs_planar(
+        u_pl, cs_pl, d_matrix, w, k=u.shape[-7], block_e=block_e,
+        inv_w_end=inv_w_end, jac=jac, delta=delta, mu=mu, prandtl=prandtl,
+        prandtl_turb=prandtl_turb, forcing_a0=forcing_a0, k_tke=k_tke,
+        interpret=interpret)
+    return from_planar_batch(out, u.shape)

@@ -33,7 +33,10 @@ The scopes the training step carries (see `fleet/superbatch.py`,
     fleet.rollout      bank draw, noise, scan plumbing, observe and reward
     rollout.policy     policy forward inside the rollout scan
     solver.rk_substep  RK stage arithmetic
-    rhs.layout         layout work around the fused RHS kernel
+    rhs.layout         conversions to and from the fused RHS kernel's
+                       planar layout: once per RL interval around the RK
+                       loop, around each call of the natural-layout
+                       wrapper; and the kernel's constant columns
     fleet.dispatch     (host span) key derivation and the step's dispatch
 """
 from __future__ import annotations

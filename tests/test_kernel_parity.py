@@ -10,6 +10,7 @@ is what lets kernels default ON for TPU runs (kernels.default_impl()):
 any future kernel edit that drifts from the oracle fails here first.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +107,84 @@ def test_fused_rhs_oracle_matches_solver_assembly():
     got = ref.navier_stokes_rhs_fused(u, cs, ops_d["D"], ops_d["w"], **kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# --- planar RK carry --------------------------------------------------------
+def test_planar_batch_round_trip():
+    """The interval's conversions: the batch padded to whole kernel blocks
+    with copies of mesh 0 (3 meshes of 4^3 elements, 2 per 128-lane block),
+    and back to the natural layout exactly."""
+    from repro.kernels.rhs import from_planar_batch, to_planar_batch
+
+    cfg = HITConfig(n_poly=2, n_elem=4, use_kernels=False)
+    u = _synthetic_state(jax.random.PRNGKey(10), (3,), cfg)
+    cs = jax.random.uniform(jax.random.PRNGKey(11), u.shape[:-1])
+    u_pl, cs_pl, block_e = to_planar_batch(u, cs)
+    assert block_e == 2
+    assert u_pl.shape == (5, 27, 4 * 64) and cs_pl.shape == (27, 4 * 64)
+    np.testing.assert_array_equal(np.asarray(u_pl[..., 3 * 64:]),
+                                  np.asarray(u_pl[..., :64]))
+    np.testing.assert_array_equal(np.asarray(cs_pl[:, 3 * 64:]),
+                                  np.asarray(cs_pl[:, :64]))
+    np.testing.assert_array_equal(
+        np.asarray(from_planar_batch(u_pl, u.shape)), np.asarray(u))
+    np.testing.assert_array_equal(
+        np.asarray(from_planar_batch(cs_pl[None], cs.shape + (1,))[..., 0]),
+        np.asarray(cs))
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _per_call_interval(u, cs_elem, cfg):
+    """The RL interval on the natural-layout carry, the fused RHS wrapper
+    converting into and out of the planar layout around every call."""
+    dtype = cfg.compute_dtype
+    ops_d = cfg.operators()
+    ops_d = dict(ops_d, D=ops_d["D"].astype(dtype), w=ops_d["w"].astype(dtype))
+    cs = solver.broadcast_cs(cs_elem, cfg).astype(dtype)
+
+    def body(x, _):
+        return solver.rk_substep(
+            x, lambda y: solver.navier_stokes_rhs(y, cs, cfg, ops_d),
+            cfg.dt), None
+
+    x, _ = jax.lax.scan(body, u.astype(dtype), None, length=cfg.n_substeps)
+    return x.astype(jnp.float32)
+
+
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_planar_rk_carry_matches_per_call_layout(precision):
+    """`advance_rl_interval` with kernels on keeps the RK carry planar for
+    the whole interval; it gives the same bits as the natural-layout carry
+    with a layout round trip around each RHS call (3 meshes, so the lanes
+    need padding)."""
+    cfg = HITConfig(n_poly=1, n_elem=4, dt_rl=0.04, use_kernels=True,
+                    precision=precision)
+    assert cfg.n_substeps > 1
+    u = _synthetic_state(jax.random.PRNGKey(12), (3,), cfg)
+    cs_elem = jax.random.uniform(jax.random.PRNGKey(13), (3, 4, 4, 4),
+                                 maxval=0.3)
+    got = solver.advance_rl_interval(u, cs_elem, cfg)
+    want = _per_call_interval(u, cs_elem, cfg)
+    assert got.shape == u.shape and got.dtype == jnp.float32
+    assert np.all(np.isfinite(np.asarray(got)))
+    assert not np.array_equal(np.asarray(got), np.asarray(u))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_planar_rhs_ops_dispatch_matches_ref():
+    """The planar dispatch: the kernel ("kernel" forced, interpret off-TPU)
+    against `ref.navier_stokes_rhs_planar` on the whole array."""
+    from repro.kernels.rhs import to_planar_batch
+
+    cfg = HITConfig(n_poly=1, n_elem=4, use_kernels=False)
+    ops_d, kw = _fused_rhs_kwargs(cfg)
+    u = _synthetic_state(jax.random.PRNGKey(14), (3,), cfg)
+    u_pl, cs_pl, block_e = to_planar_batch(u, jnp.full(u.shape[:-1], 0.17))
+    got, want = (ops.navier_stokes_rhs_planar(
+        u_pl, cs_pl, ops_d["D"], ops_d["w"], k=4, block_e=block_e,
+        impl=impl, **kw) for impl in ("kernel", "ref"))
+    assert got.shape == u_pl.shape and got.dtype == u_pl.dtype
+    _assert_close("navier_stokes_rhs_fused", jnp.float32, got, want)
 
 
 # --- dg_derivative3 ---------------------------------------------------------

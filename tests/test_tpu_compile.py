@@ -6,19 +6,25 @@ on the chip (layouts it cannot lower, VMEM overruns) — which interpret-mode
 parity tests cannot see.  Sizes are the paper's Table 1 meshes (24 and 32
 DOF: N = 5 and 7, 4^3 elements) at a fleet of 16 environments.  Each test
 asserts the compiled program holds the Mosaic kernel (`tpu_custom_call`).
+The RL interval (`solver.advance_rl_interval` with kernels on) is compiled
+whole, to check that its RK loop runs on the kernel's planar layout.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.  Nothing runs on a device; these are compiles only.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.cfd import solver
 from repro.configs import relexi_hit
+from repro.kernels import policy
 from repro.kernels.dg_derivative import dg_derivative3
 from repro.kernels.rhs import fused_navier_stokes_rhs
 from repro.kernels.smagorinsky import smagorinsky_nut
@@ -97,3 +103,56 @@ def test_wall_model_tau_compiles_for_v5e(one_chip, dof):
                                     interpret=False),
         one_chip, faces, faces)
     _assert_kernel(compiled)
+
+
+def _computations(text: str) -> dict[str, list[str]]:
+    """{computation name: its instruction lines} of an HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and "=" in line:
+            cur.append(line.split(", metadata=")[0])
+    return comps
+
+
+def _reachable(comps: dict, root: str) -> list[str]:
+    """Instruction lines of `root` and of every computation it calls."""
+    out, todo, seen = [], [root], set()
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps.get(name, []):
+            out.append(line)
+            todo += re.findall(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)",
+                               line)
+    return out
+
+
+@pytest.mark.parametrize("dof", sorted(CONFIGS))
+def test_rl_interval_steps_planar_carry_on_v5e(one_chip, dof, monkeypatch):
+    """The substep loop of `advance_rl_interval` holds the Mosaic kernel and
+    no array of the natural layout's rank (the 8-D state, its 7-D
+    coefficients, their transposes): the relayouts stay at the interval's
+    boundary, outside the RK loop."""
+    # the kernel as on the chip: compiled, not interpreted (the backend
+    # seen here is the CPU)
+    monkeypatch.setattr(policy, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(CONFIGS[dof], use_kernels=True)
+    n, k = cfg.n_poly + 1, cfg.n_elem
+    compiled = _compile(
+        lambda u, cs: solver.advance_rl_interval(u, cs, cfg), one_chip,
+        (N_ENVS, k, k, k, n, n, n, 5), (N_ENVS, k, k, k))
+    comps = _computations(compiled.as_text())
+    bodies = [b for lines in comps.values() for line in lines
+              if " while(" in line
+              for b in re.findall(r"body=%([\w.\-]+)", line)]
+    assert len(bodies) == 1     # the substep scan
+    body = _reachable(comps, bodies[0])
+    assert sum("tpu_custom_call" in line for line in body) == 5  # RK stages
+    ranks = {len(dims.split(",")) for line in body
+             for dims in re.findall(r"\b[a-z]+[0-9]*\[([0-9,]+)\]", line)}
+    assert max(ranks) < 7, sorted(ranks)
