@@ -47,7 +47,10 @@ def reader_path(metric: str) -> Path:
 
 def validate(manifest: dict) -> None:
     """Every name in the manifest resolves to its file; every per-layer
-    metric moves an end-to-end metric that each of its cells reports."""
+    metric names its cells, and moves an end-to-end metric that each of
+    them reports; every cell reports a per-layer metric.  An end-to-end
+    metric without a `workloads` list reaches every cell, so a new cell
+    joins by new entries and new files alone."""
     errors = []
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
     cells = {w["name"]: w for w in manifest["workloads"]}
@@ -70,6 +73,9 @@ def validate(manifest: dict) -> None:
                           f"{traffic_path(w['traffic'])}")
         if not limits_path(w["name"]).is_file():
             errors.append(f"cell {w['name']}: no {limits_path(w['name'])}")
+    listed = {c for m in manifest["per_layer"] for c in m.get("workloads", [])}
+    for cell in cells.keys() - listed:
+        errors.append(f"cell {cell}: reports no per-layer metric")
     for m in manifest["per_layer"]:
         if not reader_path(m["name"]).is_file():
             errors.append(f"metric {m['name']}: no reader "
@@ -78,7 +84,11 @@ def validate(manifest: dict) -> None:
             errors.append(f"metric {m['name']}: moves unknown end-to-end "
                           f"metric {m['moves']}")
             continue
-        for cell in m.get("workloads", list(cells)):
+        if "workloads" not in m:
+            errors.append(f"metric {m['name']}: names no cells "
+                          f"(a `workloads` list)")
+            continue
+        for cell in m["workloads"]:
             if cell not in cells:
                 errors.append(f"metric {m['name']}: unknown cell {cell}")
             elif not reports(cell, m["moves"]):
@@ -93,9 +103,7 @@ def cell_metrics(manifest: dict, cell: str) -> tuple[list, list]:
     """(end-to-end, per-layer) metric entries that `cell` reports."""
     e2e = [m for m in manifest["end_to_end"]
            if cell in m.get("workloads", [cell])]
-    per_layer = [m for m in manifest["per_layer"]
-                 if cell in m.get("workloads", [
-                     w["name"] for w in manifest["workloads"]])]
+    per_layer = [m for m in manifest["per_layer"] if cell in m["workloads"]]
     return e2e, per_layer
 
 

@@ -15,6 +15,7 @@ block).
 """
 from __future__ import annotations
 
+import functools
 import gc
 import math
 import time
@@ -55,12 +56,20 @@ def build_runner(config: dict, n_envs: int, workdir: str, **env_overrides):
 def make_inputs(config: dict, seed_key):
     """(reference, bank, weights, rollout key) of one run: the bank is the
     configuration's fixed set of initial states, the weights and the
-    rollout key come from the seed."""
+    rollout key come from the seed.  The initial policy's mean C_s, its
+    spread over elements (`head_scale`) and its std are the
+    configuration's `initial_policy`: a range in which no element's C_s
+    falls to where the solver blows up."""
     ref = HITReference(config["physics"])
     a = config["assumed"]
+    pol = a["initial_policy"]
     bank = ref.bank(jax.random.PRNGKey(a["bank_seed"]), a["bank_size"])
     k_w, k_run = jax.random.split(seed_key)
-    params = jax.jit(ref_ppo.init_params, static_argnums=(1, 2, 3, 4))(
+    init = functools.partial(
+        ref_ppo.init_params, log_std=math.log(pol["cs_std"]),
+        mean_share=pol["cs_mean"] / config["physics"]["cs_max"],
+        head_scale=pol["head_scale"])
+    params = jax.jit(init, static_argnums=(1, 2, 3, 4))(
         k_w, config["registry"], 3 * ref.n**3, a["d_embed"],
         a["n_shared_layers"])
     return ref, bank, params, k_run

@@ -38,11 +38,15 @@ class PPOSettings(NamedTuple):
 
 
 def init_params(key, name: str, in_features: int, d_embed: int,
-                n_shared: int, log_std: float = -1.6) -> dict:
-    """Weights from the seed: w ~ N(0, 1/d_in), zero biases."""
-    def dense(k, d_in, d_out):
+                n_shared: int, log_std: float = -1.6,
+                mean_share: float = 0.5, head_scale: float = 1.0) -> dict:
+    """Weights from the seed: w ~ N(0, 1/d_in), zero biases; the actor's
+    output weights scaled by `head_scale` and its bias set so that the
+    mean action starts near `mean_share` of cs_max."""
+    def dense(k, d_in, d_out, scale=1.0, bias=0.0):
         return {"w": jax.random.normal(k, (d_in, d_out), jnp.float32)
-                / math.sqrt(d_in), "b": jnp.zeros((d_out,), jnp.float32)}
+                * (scale / math.sqrt(d_in)),
+                "b": jnp.full((d_out,), bias, jnp.float32)}
 
     keys = iter(jax.random.split(key, 2 * n_shared + 4))
     shared = {part: [dense(next(keys), d_embed, d_embed)
@@ -50,7 +54,8 @@ def init_params(key, name: str, in_features: int, d_embed: int,
               for part in ("actor", "critic")}
     head = {"actor_in": dense(next(keys), in_features, d_embed),
             "critic_in": dense(next(keys), in_features, d_embed),
-            "actor_out": dense(next(keys), d_embed, 1),
+            "actor_out": dense(next(keys), d_embed, 1, head_scale,
+                               math.log(mean_share / (1.0 - mean_share))),
             "critic_out": dense(next(keys), d_embed, 1),
             "log_std": jnp.full((), log_std, jnp.float32)}
     return {"shared": shared, "heads": {name: head}}

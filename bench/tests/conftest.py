@@ -16,13 +16,31 @@ for p in (ROOT, ROOT / "src"):
 
 DATA = Path(__file__).resolve().parent / "data"
 
+from bench import common  # noqa: E402
+
+CELLS = {w["name"]: w for w in
+         common.load_json(ROOT / "BENCHMARK.json")["workloads"]}
+
+
+def _traffic(cell: str) -> dict:
+    return common.load_json(common.traffic_path(CELLS[cell]["traffic"]))
+
+
+TRAIN_CELLS = [c for c in CELLS if _traffic(c)["driver"] == "train"]
+
+
+def tiny_traffic(cell: str) -> dict:
+    """The test-size training mix, comparing as many iterations as the
+    cell's own traffic does."""
+    return dict(common.load_json(DATA / "train_tiny.json"),
+                steps_compared=_traffic(cell)["steps_compared"])
+
 
 class TestHarness:
     """The driver-facing part of bench/run.py's Harness, without the chip."""
 
     def __init__(self, config, traffic, limits, seed=7, seconds=1.0):
         import jax
-        from bench import common
 
         self.cell = {"name": "test", "chips": 1}
         self.config = common.load_json(DATA / config)

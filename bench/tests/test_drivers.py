@@ -7,13 +7,16 @@ import pytest
 
 from bench import common
 from bench.drivers import serve, train
-from conftest import DATA
+from bench.reference import ppo
+from conftest import CELLS, DATA, TRAIN_CELLS, tiny_traffic
 
 
-def _train(harness, seed=7):
-    lim = common.load_json(common.limits_path("hit24_train_fleet64"))
-    out = train.run(harness("hit_tiny.json", "train_tiny.json", lim, seed,
-                            seconds=1.0))
+def _train(harness, cell, seed=7):
+    h = harness("hit_tiny.json", "train_tiny.json",
+                common.load_json(common.limits_path(cell)), seed,
+                seconds=1.0)
+    h.traffic = tiny_traffic(cell)
+    out = train.run(h)
     return out, all(c.ok for c in out.checks)
 
 
@@ -24,11 +27,32 @@ def _serve(harness, seed=7):
     return out, all(c.ok for c in out.checks)
 
 
-def test_sound_training_run_is_correct(harness):
-    out, ok = _train(harness)
+@pytest.mark.parametrize("cell", TRAIN_CELLS)
+def test_sound_training_run_is_correct(harness, cell):
+    out, ok = _train(harness, cell)
     assert ok, [(c.name, c.value) for c in out.checks]
     assert out.attempted > 0 and out.failed == 0
     assert out.e2e["env_steps_per_s"] > 0
+
+
+# Uniform C_s 0.05 turns one env of two non-finite within one RL interval
+# at 32 DOF (the reference, bank seed 0); C_s 0 turns every env.
+CS_BLOWS_UP = 0.05
+
+
+@pytest.mark.parametrize("config", sorted({c["config"] for c in CELLS.values()
+                                           if c["name"] in TRAIN_CELLS}))
+def test_initial_policy_keeps_cs_where_the_solver_stays_finite(config):
+    """Over the bank's states, the initial mean action less five of the
+    policy's std stays above the C_s at which the solver blows up."""
+    cfg = common.load_json(common.config_path(config))
+    ref, bank, params, _ = train.make_inputs(cfg, common.seed_key(2**31 + 9))
+    feats = ppo.features(ref.observe(ref.to_planar(bank)))
+    mean = ppo.actor_mean(params, cfg["registry"], feats,
+                          cfg["physics"]["cs_max"])
+    std = float(jnp.exp(params["heads"][cfg["registry"]]["log_std"]))
+    assert float(mean.min()) - 5 * std > CS_BLOWS_UP
+    assert float(mean.max()) + 5 * std < cfg["physics"]["cs_max"]
 
 
 def _unchanged(self, params, opt_state, broker, k, keys):
@@ -57,7 +81,8 @@ def _reward_altered(slice_traj):
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
                                    "reward_altered"])
-def test_training_faults_are_not_correct(harness, monkeypatch, fault):
+@pytest.mark.parametrize("cell", TRAIN_CELLS)
+def test_training_faults_are_not_correct(harness, monkeypatch, cell, fault):
     from repro.fleet import superbatch
 
     if fault == "state_unchanged":
@@ -68,7 +93,7 @@ def test_training_faults_are_not_correct(harness, monkeypatch, fault):
     else:
         monkeypatch.setattr(superbatch, "slice_traj",
                             _reward_altered(superbatch.slice_traj))
-    out, ok = _train(harness, seed=11)
+    out, ok = _train(harness, cell, seed=11)
     assert not ok, [(c.name, c.value, c.limit) for c in out.checks]
 
 
