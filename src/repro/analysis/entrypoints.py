@@ -3,7 +3,7 @@
 These are the programs whose compiled form IS the product — the per-step
 solver advance, the fleet rollout, the PPO/fleet updates, the fused RHS
 mega-kernel (natural-layout wrapper and the planar entry the RK loop
-calls), and the broker's donated push.  `jaxpr_audit.audit_entry`
+calls), the channel's wall variant of it, and the broker's donated push.  `jaxpr_audit.audit_entry`
 traces each one at a reduced (but structurally faithful) shape and checks
 the resulting jaxpr/StableHLO against the compiled-program invariants; the
 trace auditor re-drives a subset through a reduced training run and pins
@@ -250,6 +250,28 @@ def _build_fused_rhs_planar() -> Built:
         args=(u_pl, cs_pl))
 
 
+def _build_wall_rhs_planar() -> Built:
+    import jax
+    import jax.numpy as jnp
+
+    from ..cfd import channel as channel_mod
+    from ..cfd.channel import ChannelConfig
+    from ..kernels import rhs as rhs_mod
+
+    cfg = ChannelConfig(n_elem=(2, 4, 2), use_kernels=False)
+    ops_d = cfg.operators()
+    u = channel_mod.make_state_bank(jax.random.PRNGKey(1), cfg, 2)
+    kx, _, kz = cfg.n_elem
+    scale = jnp.ones((2, kx, kz), u.dtype)
+    u_pl, scale_pl, block_e = rhs_mod.to_planar_wall_batch(
+        u, channel_mod.wall_scale_elements(scale, scale, cfg))
+    return Built(
+        fn=lambda u, s: rhs_mod.fused_wall_rhs_planar(
+            u, s, ops_d["D"], ops_d["w"], k=cfg.n_elem, block_e=block_e,
+            interpret=True, **channel_mod.wall_rhs_scalars(cfg, ops_d)),
+        args=(u_pl, scale_pl))
+
+
 def _build_serve_step() -> Built:
     import jax
     import jax.numpy as jnp
@@ -291,6 +313,7 @@ ENTRYPOINTS: tuple[EntryPoint, ...] = (
     EntryPoint("broker_push", _build_broker_push),
     EntryPoint("fused_rhs", _build_fused_rhs),
     EntryPoint("fused_rhs_planar", _build_fused_rhs_planar),
+    EntryPoint("wall_rhs_planar", _build_wall_rhs_planar),
     EntryPoint("serve_step", _build_serve_step),
 )
 

@@ -117,6 +117,19 @@ def _kernel_cases() -> dict[str, Callable[[], tuple]]:
             prandtl_turb=cfg.prandtl_turb, forcing_a0=cfg.forcing_a0,
             k_tke=cfg.k_tke, interpret=True)
 
+    def wall_rhs_planar():
+        from ..cfd import channel
+        from ..cfd.channel import ChannelConfig
+        from ..kernels.rhs import fused_wall_rhs_planar as fn
+        cfg = ChannelConfig(n_elem=(2, 4, 2), use_kernels=False)
+        ops = cfg.operators()
+        # 16 meshes of 2 x 4 x 2 elements: two 128-lane blocks of 8 meshes
+        u = jnp.zeros((5, 64, 256), jnp.float32)
+        scale = jnp.ones((1, 256), jnp.float32)
+        return fn, (u, scale, ops["D"], ops["w"]), dict(
+            k=cfg.n_elem, block_e=8, interpret=True,
+            **channel.wall_rhs_scalars(cfg, ops))
+
     def flash_attention():
         from ..kernels.flash_attention import flash_attention as fn
         q = jnp.zeros((1, 2, 64, 16), jnp.float32)
@@ -136,6 +149,7 @@ def _kernel_cases() -> dict[str, Callable[[], tuple]]:
         "wall_model_tau": wall_model_tau,
         "fused_rhs": fused_rhs,
         "fused_rhs_planar": fused_rhs_planar,
+        "wall_rhs_planar": wall_rhs_planar,
         "flash_attention": flash_attention,
         "linear_scan": linear_scan,
     }

@@ -46,9 +46,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import dgsem, equations, gll
+from ..kernels import ref as kref
 from ..kernels.ref import reichardt_uplus  # canonical formula (kernel oracle)
 from .equations import GasParams
-from .solver import _RK_A, _RK_B, kernel_grad_nut
+from .solver import kernel_grad_nut, rk_substep
 
 
 @dataclasses.dataclass(frozen=True)
@@ -188,6 +189,20 @@ class ChannelConfig:
         (rho cp u_tau) degenerates to it when q_w is the frictional
         dissipation tau_w u_tau) — the temperature-channel normalization."""
         return self.u_tau**2 / equations.CP
+
+    @property
+    def y_match(self) -> float:
+        """Wall-model matching height: the wall element's centroid."""
+        return 0.5 * self.dxs[1]
+
+    @property
+    def wall_params(self) -> kref.WallParams:
+        """Static scalars of the fused wall RHS (kernels/rhs.py)."""
+        _, w = gll.gll_nodes_weights(self.n_poly)
+        return kref.WallParams(
+            y_m=self.y_match, nu=self.nu, kappa=self.kappa,
+            iters=self.wm_iters, f_x=self.f_x, cs=self.cs_sgs,
+            w_half=tuple(float(x) * 0.5 for x in w))
 
     def operators(self) -> dict:
         _, w = gll.gll_nodes_weights(self.n_poly)
@@ -398,7 +413,7 @@ def wall_fluxes(u: jax.Array, scale_bot: jax.Array, scale_top: jax.Array,
     """
     lo_tr, hi_tr = dgsem._face_slices(u, 1)
     u_wall = (_wall_slab(lo_tr, 0), _wall_slab(hi_tr, 1))
-    y_m = 0.5 * cfg.dxs[1]  # matching point: wall-element centroid distance
+    y_m = cfg.y_match
     out = []
     for side, scale in ((0, scale_bot), (1, scale_top)):
         _, _, p_w, _ = equations.conservative_to_primitive(u_wall[side])
@@ -503,18 +518,23 @@ def channel_rhs(u: jax.Array, scale_bot: jax.Array, scale_top: jax.Array,
     return rhs + forcing
 
 
-def rk_substep(u: jax.Array, scale_bot: jax.Array, scale_top: jax.Array,
-               cfg: ChannelConfig, ops: dict) -> jax.Array:
-    """One Carpenter-Kennedy RK5(4) low-storage step of size cfg.dt."""
-    dt = jnp.asarray(cfg.dt, dtype=u.dtype)
-    du = jnp.zeros_like(u)
-    for stage in range(5):
-        # cast + float(): keep the carry in the rollout compute dtype (the
-        # bf16 path; both are no-ops for fp32 — see solver.rk_substep)
-        rhs = channel_rhs(u, scale_bot, scale_top, cfg, ops).astype(u.dtype)
-        du = float(_RK_A[stage]) * du + dt * rhs
-        u = u + float(_RK_B[stage]) * du
-    return u
+def wall_scale_elements(scale_bot: jax.Array, scale_top: jax.Array,
+                        cfg: ChannelConfig) -> jax.Array:
+    """Per-wall-element stress scales (..., Kx, Kz) of both walls ->
+    one per element (..., Kx, Ky, Kz): the bottom wall's on ky = 0, the
+    top wall's on ky = Ky-1, 1 (unread) in between."""
+    ky = cfg.n_elem[1]
+    mid = jnp.ones(scale_bot.shape[:-1] + (ky - 2,) + scale_bot.shape[-1:],
+                   scale_bot.dtype)
+    return jnp.concatenate([scale_bot[..., None, :], mid,
+                            scale_top[..., None, :]], axis=-2)
+
+
+def wall_rhs_scalars(cfg: ChannelConfig, ops: dict) -> dict:
+    """The static scalars of the fused wall RHS kernel (kernels/rhs.py)."""
+    return dict(inv_w_end=ops["inv_w_end"], jac=cfg.jacs,
+                delta=cfg.delta_filter, mu=cfg.gas.mu, prandtl=cfg.prandtl,
+                prandtl_turb=cfg.prandtl_turb, wall=cfg.wall_params)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -523,23 +543,54 @@ def advance_rl_interval(u: jax.Array, scale_bot: jax.Array,
                         cfg: ChannelConfig) -> jax.Array:
     """Advance the channel LES by Delta t_RL under fixed wall-stress scaling
     (one MDP transition).  u: (..., Kx,Ky,Kz,n,n,n,5); scale_bot/scale_top:
-    per-wall-element scaling (..., Kx, Kz), broadcast to face nodes here.
+    per-wall-element scaling (..., Kx, Kz).
+
+    With `cfg.kernels_enabled` (and walls on) the RK carry stays in the
+    fused kernel's planar layout for the whole interval, as in
+    `solver.advance_rl_interval`: the state and the per-lane wall-stress
+    scales are converted once before the substep scan
+    (`to_planar_wall_batch`) and back once after it (`from_planar_batch`),
+    both under the `rhs.layout` scope, and every RK stage calls the
+    `fused_wall_rhs` kernel.  Otherwise `channel_rhs` steps the natural
+    layout, the scales broadcast to the wall-face nodes — the oracle.
+
     With `cfg.precision == "bf16"` the state advances in bfloat16 and is
     cast back to float32 at the boundary (obs/reward/PPO stay float32)."""
     ops = cfg.operators()
-    n = cfg.n
-    to_nodes = lambda s: jnp.broadcast_to(s[..., None, None],
-                                          s.shape + (n, n))
-    sb, st = to_nodes(scale_bot), to_nodes(scale_top)
     dtype = cfg.compute_dtype
-    u, sb, st = u.astype(dtype), sb.astype(dtype), st.astype(dtype)
+    u = u.astype(dtype)
+    scale_bot, scale_top = scale_bot.astype(dtype), scale_top.astype(dtype)
     if dtype != jnp.float32:
         # operator matrices must follow the compute dtype or every DG
         # contraction re-promotes the bf16 carry to f32 mid-loop (JAX002)
         ops = dict(ops, D=ops["D"].astype(dtype), w=ops["w"].astype(dtype))
 
-    def body(u, _):
-        return rk_substep(u, sb, st, cfg, ops), None
+    planar = cfg.kernels_enabled and cfg.wall
+    if planar:
+        from ..kernels import ops as kops
+        from ..kernels.rhs import from_planar_batch, to_planar_wall_batch
 
-    u, _ = jax.lax.scan(body, u, None, length=cfg.n_substeps)
-    return u.astype(jnp.float32)
+        x, scale_pl, block_e = to_planar_wall_batch(
+            u, wall_scale_elements(scale_bot, scale_top, cfg))
+
+        def rhs(x):
+            return kops.wall_rhs_planar(
+                x, scale_pl, ops["D"], ops["w"], k=cfg.n_elem,
+                block_e=block_e, impl="kernel", **wall_rhs_scalars(cfg, ops))
+    else:
+        n = cfg.n
+        to_nodes = lambda s: jnp.broadcast_to(s[..., None, None],
+                                              s.shape + (n, n))
+        sb, st = to_nodes(scale_bot), to_nodes(scale_top)
+        x = u
+
+        def rhs(x):
+            return channel_rhs(x, sb, st, cfg, ops)
+
+    def body(x, _):
+        return rk_substep(x, rhs, cfg.dt), None
+
+    x, _ = jax.lax.scan(body, x, None, length=cfg.n_substeps)
+    if planar:
+        x = from_planar_batch(x, u.shape)
+    return x.astype(jnp.float32)

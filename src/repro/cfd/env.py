@@ -35,6 +35,7 @@ class StepResult(NamedTuple):
     obs: jax.Array
     reward: jax.Array
     done: jax.Array
+    reverted: jax.Array   # bool: the blow-up guard reverted the transition
 
 
 def observe(u: jax.Array, cfg: HITConfig) -> jax.Array:
@@ -84,4 +85,5 @@ def step(state: EnvState, action: jax.Array, cfg: HITConfig,
     t_next = state.t_step + 1
     done = t_next >= cfg.n_actions
     next_state = EnvState(u=u_next, t_step=t_next)
-    return StepResult(next_state, observe(u_next, cfg), reward, done)
+    return StepResult(next_state, observe(u_next, cfg), reward, done,
+                      ~finite)

@@ -52,6 +52,9 @@ class Trajectory(NamedTuple):
     dones: jax.Array      # (T, B) bool, True where episode TERMINATES at t
     values: jax.Array     # (T, B) V(s_t) under the behavior policy
     last_value: jax.Array  # (B,) V(s_T)
+    # (T, B) bool: the env's blow-up guard reverted step t (rollouts set
+    # it; the update only counts it)
+    reverted: jax.Array | None = None
 
 
 def gae(traj: Trajectory, gamma: float, lam: float) -> tuple[jax.Array, jax.Array]:

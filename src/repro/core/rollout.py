@@ -73,12 +73,11 @@ def rollout(
             logp = policy_lib.log_prob(mean, std, action)
         val = pol.value(params, obs)
         res = env.step(state, action)
-        out = (obs, action, logp, res.reward, res.done, val)
+        out = (obs, action, logp, res.reward, res.done, val, res.reverted)
         return res.state, out
 
-    final_state, (obs, actions, log_probs, rewards, dones, values) = jax.lax.scan(
-        step_fn, state0, noise
-    )
+    final_state, (obs, actions, log_probs, rewards, dones, values,
+                  reverted) = jax.lax.scan(step_fn, state0, noise)
     last_obs = env.observe(final_state)
     last_value = pol.value(params, last_obs)
     return Trajectory(
@@ -89,6 +88,7 @@ def rollout(
         dones=dones,
         values=values,
         last_value=last_value,
+        reverted=reverted,
     )
 
 

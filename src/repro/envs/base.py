@@ -54,6 +54,7 @@ class StepResult(NamedTuple):
     obs: jax.Array
     reward: jax.Array
     done: jax.Array
+    reverted: jax.Array   # bool like `done`: the blow-up guard reverted it
 
 
 @dataclasses.dataclass(frozen=True)

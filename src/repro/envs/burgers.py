@@ -75,7 +75,8 @@ class BurgersEnv:
         t_next = state.t_step + 1
         done = t_next >= cfg.n_actions
         next_state = EnvState(u=u_next, t_step=t_next)
-        return StepResult(next_state, self.observe(next_state), reward, done)
+        return StepResult(next_state, self.observe(next_state), reward, done,
+                          ~finite)
 
 
 @register("burgers_96dof")

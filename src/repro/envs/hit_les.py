@@ -69,7 +69,8 @@ class HITLESEnv:
 
     def step(self, state: EnvState, action: jax.Array) -> StepResult:
         res = hit_kernel.step(state, action, self.cfg, self.e_dns())
-        return StepResult(EnvState(*res.state), res.obs, res.reward, res.done)
+        return StepResult(EnvState(*res.state), res.obs, res.reward, res.done,
+                          res.reverted)
 
 
 @register("hit_les_24dof")

@@ -258,4 +258,8 @@ def fleet_update(
     for name in names:
         stats[f"{name}/mean_return"] = jnp.mean(
             jnp.sum(trajs[name].rewards, axis=0))
+        if trajs[name].reverted is not None:
+            # env-steps whose advance the blow-up guard reverted
+            stats[f"{name}/reverted_envs"] = jnp.sum(
+                trajs[name].reverted.astype(jnp.float32))
     return params, opt_state, stats

@@ -104,13 +104,14 @@ def slice_traj(traj: ppo_lib.Trajectory, n_envs: int) -> ppo_lib.Trajectory:
         dones=traj.dones[:, :n_envs],
         values=traj.values[:, :n_envs],
         last_value=traj.last_value[:n_envs],
+        reverted=traj.reverted[:, :n_envs],
     )
 
 
 _TRAJ_DATA_SPEC = ppo_lib.Trajectory(
     obs=P(None, "data"), actions=P(None, "data"), log_probs=P(None, "data"),
     rewards=P(None, "data"), dones=P(None, "data"), values=P(None, "data"),
-    last_value=P("data"))
+    last_value=P("data"), reverted=P(None, "data"))
 
 
 class FleetProgram:
@@ -200,17 +201,18 @@ class FleetProgram:
                 logp = policy_lib.log_prob(mean, std, action)
                 val = pol.value(params, obs)
             res = env.step(state, action)
-            return res.state, (obs, action, logp, res.reward, res.done, val)
+            return res.state, (obs, action, logp, res.reward, res.done, val,
+                               res.reverted)
 
-        final_state, (obs, actions, log_probs, rewards, dones, values) = \
-            jax.lax.scan(step_fn, state0, noise)
+        final_state, (obs, actions, log_probs, rewards, dones, values,
+                      reverted) = jax.lax.scan(step_fn, state0, noise)
         obs_last = env.observe(final_state)
         with jax.named_scope("rollout.policy"):
             last_value = pol.value(params, obs_last)
         return ppo_lib.Trajectory(obs=obs, actions=actions,
                                   log_probs=log_probs, rewards=rewards,
                                   dones=dones, values=values,
-                                  last_value=last_value)
+                                  last_value=last_value, reverted=reverted)
 
     def rollout_shard(self, params: dict, u0s: dict, noises: dict
                       ) -> dict[str, ppo_lib.Trajectory]:

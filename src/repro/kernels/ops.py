@@ -28,6 +28,7 @@ from .linear_scan import linear_scan as _ls_pallas
 from .policy import default_impl
 from .rhs import fused_navier_stokes_rhs as _rhs_pallas
 from .rhs import fused_navier_stokes_rhs_planar as _rhs_planar_pallas
+from .rhs import fused_wall_rhs_planar as _wall_rhs_planar_pallas
 from .smagorinsky import smagorinsky_nut as _smag_pallas
 from .wall_model import wall_model_tau as _wm_pallas
 
@@ -82,6 +83,26 @@ def navier_stokes_rhs_planar(u_pl: jax.Array, cs_pl: jax.Array,
     consts = ref.planar_consts(d_matrix, w, n, k, u_pl.shape[-1] // k**3)
     planes = ref.navier_stokes_rhs_planar(u_pl, cs_pl, consts, n=n, k=k,
                                           **kw)
+    return jnp.stack(planes).astype(u_pl.dtype)
+
+
+def wall_rhs_planar(u_pl: jax.Array, scale_pl: jax.Array,
+                    d_matrix: jax.Array, w: jax.Array, *,
+                    k: tuple[int, int, int], block_e: int,
+                    impl: str | None = None, **kw) -> jax.Array:
+    """The wall-modelled channel's fused RHS on planar operands
+    (kernels/rhs.to_planar_wall_batch's layout) — the op
+    `cfd/channel.advance_rl_interval` steps its planar RK carry with when
+    kernels are enabled.  `kw`: the scalars of `fused_wall_rhs_planar`."""
+    if (impl or default_impl()) == "kernel":
+        return _wall_rhs_planar_pallas(u_pl, scale_pl, d_matrix, w, k=k,
+                                       block_e=block_e, **kw)
+    n = d_matrix.shape[0]
+    consts = ref.planar_consts(d_matrix, w, n, k,
+                               u_pl.shape[-1] // (k[0] * k[1] * k[2]))
+    planes = ref.navier_stokes_rhs_planar(
+        u_pl, scale_pl, consts, n=n, k=k, wall_consts=ref.wall_consts(n),
+        **kw)
     return jnp.stack(planes).astype(u_pl.dtype)
 
 

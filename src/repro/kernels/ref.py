@@ -67,30 +67,38 @@ def wall_model_tau(u_par: jax.Array, rho_w: jax.Array, *, y_m: float,
     jitted RHS.  Oracle for kernels/wall_model.py (identical op order).
     """
     f32 = jnp.float32
-    up = u_par.astype(f32)
+    tau = reichardt_stress(u_par.astype(f32), rho_w.astype(f32), y_m=y_m,
+                           nu=nu, kappa=kappa, iters=iters)
+    return tau.astype(u_par.dtype)
+
+
+def reichardt_stress(up, rho_w, *, y_m: float, nu: float, kappa: float,
+                     iters: int):
+    """The fixed point of `wall_model_tau` on float32 operands: laminar
+    initial guess, `iters` damped rounds, rho u_tau^2."""
     u_tau = jnp.sqrt(nu * up / y_m + 1e-12)  # laminar initial guess
     for _ in range(iters):
         y_plus = y_m * u_tau / nu
         u_plus = jnp.maximum(reichardt_uplus(y_plus, kappa), 1e-6)
         u_tau = jnp.sqrt(u_tau * up / u_plus + 1e-14)
-    return (rho_w.astype(f32) * u_tau**2).astype(u_par.dtype)
+    return rho_w * u_tau**2
 
 
 # --- fused Navier-Stokes RHS -------------------------------------------------
-# Self-contained single-pass DGSEM RHS for the periodic HIT scenario — the
-# oracle for kernels/rhs.py.  The kernel body calls `navier_stokes_rhs_planar`
-# on its VMEM block and this module's `navier_stokes_rhs_fused` calls the
-# same function on the whole batch, so kernel and oracle share one op order
-# by construction.  The constants and formulas mirror cfd/equations +
-# cfd/dgsem; they are restated here because this module must stay a leaf
-# (imports jax only — the kernels cannot cycle through the cfd package).
+# Self-contained single-pass DGSEM RHS — the oracle for kernels/rhs.py.  The
+# kernel body calls `navier_stokes_rhs_planar` on its VMEM block and this
+# module's `navier_stokes_rhs_fused` calls the same function on the whole
+# batch, so kernel and oracle share one op order by construction.  The
+# constants and formulas mirror cfd/equations + cfd/dgsem (+ cfd/channel for
+# the wall variant); they are restated here because this module must stay a
+# leaf (imports jax only — the kernels cannot cycle through the cfd package).
 #
 # Planar layout.  The state (..., Kx, Ky, Kz, n, n, n, C) is held as C
 # planes of shape (P, L): P = n^3 node rows (r = i0 n^2 + i1 n + i2) by
-# L = batch x K^3 element lanes (l = b K^3 + e0 K^2 + e1 K + e2).  Both
-# minor dims are tile-dense for Mosaic (P is a multiple of 8 for even n,
-# a grid block spans a multiple of 128 lanes), and every operation is
-# elementwise, a static roll, a select or a reduction:
+# L = batch x Kx Ky Kz element lanes (l = b Kx Ky Kz + e0 Ky Kz + e1 Kz +
+# e2).  Both minor dims are tile-dense for Mosaic (P is a multiple of 8 for
+# even n, a grid block spans a multiple of 128 lanes), and every operation
+# is elementwise, a static roll, a select or a reduction:
 #   * node-axis derivatives (and the split-form two-point sums) are sums
 #     over row offsets o of D[i, i+o] * roll(x, -o s_d) with s_d the row
 #     stride of node axis d — the per-row coefficients come in as (P, 1)
@@ -103,7 +111,18 @@ def wall_model_tau(u_par: jax.Array, rho_w: jax.Array, *, y_m: float,
 #   * the Lundgren forcing's whole-box means are a weighted row sum and a
 #     per-environment masked lane sum.
 # Face quantities are evaluated on every row and read only on the face
-# rows, so no operation changes shape.
+# rows, so no operation changes shape — except in the wall variant's
+# matching-point inversion (`_wall_stress`), which runs on the n^2 wall-face
+# columns gathered into the leading rows.
+#
+# Wall variant (`WallParams`, the wall-modelled channel of cfd/channel.py):
+# y is walled, x and z periodic.  On the first / last element lanes along y
+# the wrapped faces take the wall values: the BR1 gradient trace is the
+# interior trace with v_y = 0, and the +y numerical flux is the
+# no-penetration pressure flux minus the modelled stress tau_w = a rho
+# u_tau^2 along the matching-point velocity (Reichardt's law inverted at the
+# y-quadrature mean of the wall element's column).  C_s is a constant and
+# the forcing is the constant pressure gradient f_x.
 
 _GAMMA = 1.4
 _R_GAS = 1.0
@@ -128,6 +147,40 @@ class PlanarConsts(NamedTuple):
     lmask: jax.Array
     emask: jax.Array
     wq: jax.Array
+
+
+class WallConsts(NamedTuple):
+    """Row masks of the wall variant's column gather (`wall_consts`).
+
+    gather  (n, P, 1)  1.0 on the gathered rows i0 n .. i0 n + n-1 that
+                       receive the y-lo face row (i0, 0, i2) of node0 i0
+    scatter (n, P, 1)  1.0 on the y-lo face rows (i0, 0, i2) of node0 i0
+    """
+
+    gather: jax.Array
+    scatter: jax.Array
+
+
+class WallParams(NamedTuple):
+    """Static scalars of the wall variant (all hashable, jit-static).
+
+    y_m the matching height, nu the kinematic viscosity, kappa and iters
+    the Reichardt inversion, f_x the pressure-gradient forcing, cs the
+    interior Smagorinsky coefficient, w_half the GLL weights over 2 (the
+    y-quadrature of the matching mean)."""
+
+    y_m: float
+    nu: float
+    kappa: float
+    iters: int
+    f_x: float
+    cs: float
+    w_half: tuple[float, ...]
+
+
+def mesh3(k) -> tuple[int, int, int]:
+    """Element counts (Kx, Ky, Kz) of a cubic K or an anisotropic grid."""
+    return (k, k, k) if isinstance(k, int) else tuple(k)
 
 
 def to_planar(u: jax.Array) -> jax.Array:
@@ -186,24 +239,34 @@ def planar_deriv(x, coef, n: int, d: int, roll=jnp.roll):
     return out
 
 
-def _planar_masks(n: int, k: int, n_env: int) -> tuple[np.ndarray, ...]:
+def _elem_strides(k) -> tuple[int, int, int]:
+    """Lane stride of each element axis: (Ky Kz, Kz, 1)."""
+    _, ky, kz = mesh3(k)
+    return (ky * kz, kz, 1)
+
+
+def _planar_masks(n: int, k, n_env: int) -> tuple[np.ndarray, ...]:
     """Host tables of `planar_consts`: the float32 rmask, lmask, emask."""
     node = _node_index(n)
+    km, t = mesh3(k), _elem_strides(k)
+    cells = km[0] * km[1] * km[2]
     rmask = np.stack([node[d] == e for d in range(3) for e in (0, n - 1)])
-    lanes = np.arange(n_env * k**3)
-    elem = [lanes // k ** (2 - d) % k for d in range(3)]
-    lmask = np.stack([elem[d] == e for d in range(3) for e in (0, k - 1)])
-    emask = lanes[None, :] // k**3 == np.arange(n_env)[:, None]
+    lanes = np.arange(n_env * cells)
+    elem = [lanes // t[d] % km[d] for d in range(3)]
+    lmask = np.stack([elem[d] == e for d in range(3)
+                      for e in (0, km[d] - 1)])
+    emask = lanes[None, :] // cells == np.arange(n_env)[:, None]
     f32 = np.float32
     return (rmask.astype(f32)[..., None], lmask.astype(f32)[:, None, :],
             emask.astype(f32)[:, None, :])
 
 
-def planar_consts(d_matrix: jax.Array, w: jax.Array, n: int, k: int,
+def planar_consts(d_matrix: jax.Array, w: jax.Array, n: int, k,
                   n_env: int) -> PlanarConsts:
-    """Constants for `n_env` periodic K^3-element meshes of n^3 nodes.  The
-    masks and index tables are built on the host, so a compiled program
-    holds them as literals and computes nothing for them per call."""
+    """Constants for `n_env` meshes of n^3-node elements, K^3 (an int k)
+    or Kx x Ky x Kz (a 3-tuple).  The masks and index tables are built on
+    the host, so a compiled program holds them as literals and computes
+    nothing for them per call."""
     node = _node_index(n)
     rmask, lmask, emask = _planar_masks(n, k, n_env)
     w2 = w.astype(jnp.float32) * 0.5  # reference [-1,1] -> unit mass
@@ -216,6 +279,19 @@ def planar_consts(d_matrix: jax.Array, w: jax.Array, n: int, k: int,
         wq=wq[:, None])
 
 
+def wall_consts(n: int) -> WallConsts:
+    """Host-built row masks of the wall variant's column gather."""
+    rows = np.arange(n**3)
+    node = _node_index(n)
+    gather = np.stack([(rows // n == i0) & (rows < n * n)
+                       for i0 in range(n)])
+    scatter = np.stack([(node[0] == i0) & (node[1] == 0)
+                        for i0 in range(n)])
+    f32 = np.float32
+    return WallConsts(gather=jnp.asarray(gather.astype(f32)[..., None]),
+                      scatter=jnp.asarray(scatter.astype(f32)[..., None]))
+
+
 def kernel_roll(x, shift: int, axis: int):
     """`jnp.roll` by a static shift as Mosaic's rotate (kernel bodies);
     the same values as `jnp.roll`, which the XLA oracle uses."""
@@ -223,11 +299,12 @@ def kernel_roll(x, shift: int, axis: int):
 
 
 class _Planar:
-    """Stencil operators of one planar block (n^3 node rows, K^3-element
-    lanes per environment)."""
+    """Stencil operators of one planar block (n^3 node rows, Kx Ky Kz
+    element lanes per environment)."""
 
-    def __init__(self, consts, n: int, k: int, roll):
-        self.c, self.n, self.k, self._roll_fn = consts, n, k, roll
+    def __init__(self, consts, n: int, k, roll):
+        self.c, self.n, self._roll_fn = consts, n, roll
+        self.k, self.t = mesh3(k), _elem_strides(k)
 
     def roll(self, x, shift: int, axis: int):
         shift %= x.shape[axis]
@@ -236,18 +313,24 @@ class _Planar:
     def deriv(self, x, d: int):
         return planar_deriv(x, self.c.coef, self.n, d, self._roll_fn)
 
+    def first(self, d: int):
+        """Lanes of the first element along element axis d."""
+        return self.c.lmask[2 * d] > 0.5
+
+    def last(self, d: int):
+        """Lanes of the last element along element axis d."""
+        return self.c.lmask[2 * d + 1] > 0.5
+
     def next_elem(self, x, d: int):
         """x of the +1 neighbour along element axis d (periodic)."""
-        t = self.k ** (2 - d)
-        last = self.c.lmask[2 * d + 1] > 0.5
-        return jnp.where(last, self.roll(x, (self.k - 1) * t, 1),
+        k, t = self.k[d], self.t[d]
+        return jnp.where(self.last(d), self.roll(x, (k - 1) * t, 1),
                          self.roll(x, -t, 1))
 
     def prev_elem(self, x, d: int):
         """x of the -1 neighbour along element axis d (periodic)."""
-        t = self.k ** (2 - d)
-        first = self.c.lmask[2 * d] > 0.5
-        return jnp.where(first, self.roll(x, -(self.k - 1) * t, 1),
+        k, t = self.k[d], self.t[d]
+        return jnp.where(self.first(d), self.roll(x, -(k - 1) * t, 1),
                          self.roll(x, t, 1))
 
     def lo_of_next(self, x, d: int):
@@ -370,26 +453,81 @@ def _viscous_flux(u, grad, nu_t, direction: int, mu: float,
     return [jnp.zeros_like(rho), *tau_d, work - q_d]
 
 
+def _wall_stress(u, scale, geo: _Planar, wc: WallConsts, wall: WallParams):
+    """Modelled viscous wall flux (tau u_x / |u_par|, tau u_z / |u_par|)
+    of each lane's y-face columns, on the y-lo and y-hi face rows (zero on
+    the other rows): tau = scale * rho u_tau^2 from Reichardt's law at the
+    y-quadrature mean (rho, rho u_x, rho u_z) of the column.
+
+    The column means land on the y-lo face rows (i0, 0, i2); those n^2
+    rows are gathered into the leading rows, so the inversion's `iters`
+    rounds run on ceil8(n^2) rows, not n^3, and scattered back.  scale:
+    the (1, L) row of per-lane wall-stress scales."""
+    n = geo.n
+    p_rows, lanes = u[0].shape
+    m = min(p_rows, -(-n * n // 8) * 8)
+
+    def column_mean(x):   # valid on the y-lo face rows
+        acc = None
+        for j, w in enumerate(wall.w_half):
+            term = w * geo.roll(x, -j * n, 0)
+            acc = term if acc is None else acc + term
+        return acc
+
+    def gather(x):
+        acc = x
+        for i0 in range(1, n):
+            acc = jnp.where(wc.gather[i0] > 0.5,
+                            geo.roll(x, -i0 * (n * n - n), 0), acc)
+        return acc[:m]
+
+    def scatter(c):
+        if m < p_rows:
+            c = jnp.concatenate(
+                [c, jnp.zeros((p_rows - m, lanes), c.dtype)], axis=0)
+        lo = jnp.where(wc.scatter[0] > 0.5, c, 0.0)
+        for i0 in range(1, n):
+            lo = jnp.where(wc.scatter[i0] > 0.5,
+                           geo.roll(c, i0 * (n * n - n), 0), lo)
+        hi = geo.roll(lo, (n - 1) * n, 0)
+        return jnp.where(geo.c.rmask[2] > 0.5, lo, hi)
+
+    rho_m = gather(column_mean(u[0]))
+    ux_m = gather(column_mean(u[1])) / rho_m
+    uz_m = gather(column_mean(u[3])) / rho_m
+    u_par = jnp.sqrt(ux_m * ux_m + uz_m * uz_m + 1e-12)
+    tau = scale * reichardt_stress(u_par, rho_m, y_m=wall.y_m, nu=wall.nu,
+                                   kappa=wall.kappa, iters=wall.iters)
+    return scatter(tau * ux_m / u_par), scatter(tau * uz_m / u_par)
+
+
 def navier_stokes_rhs_planar(
-    u, cs, consts: PlanarConsts, *, n: int, k: int,
-    inv_w_end: tuple[float, float], jac: float, delta: float, mu: float,
-    prandtl: float, prandtl_turb: float, forcing_a0: float, k_tke: float,
-    roll=jnp.roll,
+    u, cs, consts: PlanarConsts, *, n: int, k,
+    inv_w_end: tuple[float, float], jac, delta: float, mu: float,
+    prandtl: float, prandtl_turb: float, forcing_a0: float = 0.0,
+    k_tke: float = 0.0, wall: WallParams | None = None,
+    wall_consts: WallConsts | None = None, roll=jnp.roll,
 ) -> list:
     """The fused RHS on planar operands — the body of kernels/rhs.py.
 
     u: 5 conservative planes (indexable, e.g. a (5, P, L) array or a VMEM
-    ref); cs: the (P, L) Smagorinsky coefficient plane; consts for this
-    block (`planar_consts`).  `roll` is `jnp.roll` under XLA and
-    `kernel_roll` in a kernel body.  Returns the 5 float32 RHS planes.
+    ref); cs: the (P, L) Smagorinsky coefficient plane, or with `wall` the
+    (1, L) row of per-lane wall-stress scales; consts for this block
+    (`planar_consts`).  k: K (periodic K^3 mesh) or (Kx, Ky, Kz); jac: one
+    scalar or one per direction.  `wall` (static) selects the
+    wall-modelled channel variant, with `wall_consts`.  `roll` is
+    `jnp.roll` under XLA and `kernel_roll` in a kernel body.  Returns the
+    5 float32 RHS planes.
 
-    Pipeline (the op order of cfd/solver.navier_stokes_rhs): primitive
-    decode -> BR1 gradient of (v, T) -> Smagorinsky nu_t -> per-direction
-    split-form Kennedy-Gruber volume + LLF surface + BR1 viscous
-    divergence -> whole-box quadrature-mean forcing.
+    Pipeline (the op order of cfd/solver.navier_stokes_rhs and
+    cfd/channel.channel_rhs): primitive decode -> BR1 gradient of (v, T)
+    -> Smagorinsky nu_t -> per-direction split-form Kennedy-Gruber volume
+    + LLF surface + BR1 viscous divergence -> forcing (Lundgren's
+    whole-box means, or the wall variant's constant pressure gradient).
     """
     f32 = jnp.float32
     geo = _Planar(consts, n, k, roll)
+    jacs = tuple(jac) if isinstance(jac, (tuple, list)) else (jac,) * 3
     u = [u[c].astype(f32) for c in range(5)]
     cs = cs[...].astype(f32)
 
@@ -398,16 +536,23 @@ def navier_stokes_rhs_planar(
     prim = (rho, vel, p, e_spec)
     q_prim = [*vel, temp]
 
-    # BR1 gradient of (v, T): central interface averages, periodic wrap
+    # BR1 gradient of (v, T): central interface averages, periodic wrap;
+    # the wall variant's y walls take the interior trace with v_y = 0
     grad = [[None] * 3 for _ in range(4)]
     for d in range(3):
         for c, q in enumerate(q_prim):
             vol = geo.deriv(q, d)
             q_star_right = 0.5 * (q + geo.lo_of_next(q, d))
-            q_star_left = geo.hi_of_prev(q_star_right, d)
+            if wall is not None and d == 1:
+                q_wall = 0.0 if c == 1 else q
+                q_star_right = jnp.where(geo.last(d), q_wall, q_star_right)
+                q_star_left = jnp.where(geo.first(d), q_wall,
+                                        geo.hi_of_prev(q_star_right, d))
+            else:
+                q_star_left = geo.hi_of_prev(q_star_right, d)
             g = geo.lift(vol, q_star_right - q, q_star_left - q, d,
                          inv_w_end)
-            grad[c][d] = g * jac
+            grad[c][d] = g * jacs[d]
 
     # Smagorinsky eddy viscosity (paper Eq. 3)
     s2 = None
@@ -416,7 +561,14 @@ def navier_stokes_rhs_planar(
             s_ij = 0.5 * (grad[i][j] + grad[j][i])
             s2 = s_ij * s_ij if s2 is None else s2 + s_ij * s_ij
     s_mag = jnp.sqrt(2.0 * s2 + 1e-30)
-    nu_t = (cs * delta) ** 2 * s_mag
+    nu_t = (cs * delta if wall is None else wall.cs * delta) ** 2 * s_mag
+
+    if wall is not None:
+        # +y wall fluxes (advective - viscous): [0, -/+V_x, p, -/+V_z, 0]
+        # at the bottom / top wall (the stress's sign flips with the side)
+        v_x, v_z = _wall_stress(u, cs, geo, wall_consts, wall)
+        g_top = [0.0, v_x, p, v_z, 0.0]
+        g_bot = [0.0, -v_x, p, -v_z, 0.0]
 
     rhs = None
     for d in range(3):
@@ -434,15 +586,27 @@ def navier_stokes_rhs_planar(
             vol = vol_adv[c] - vol_visc
             f_star = f_star_adv[c] - f_star_visc
             f_nodes = f_adv_nodes[c] - f_visc[c]
-            f_star_left = geo.hi_of_prev(f_star, d)
+            if wall is not None and d == 1:
+                f_star = jnp.where(geo.last(d), g_top[c], f_star)
+                f_star_left = jnp.where(geo.first(d), g_bot[c],
+                                        geo.hi_of_prev(f_star, d))
+            else:
+                f_star_left = geo.hi_of_prev(f_star, d)
             div_d.append(geo.lift(vol, f_star - f_nodes,
-                                  f_star_left - f_nodes, d, inv_w_end) * jac)
+                                  f_star_left - f_nodes, d, inv_w_end)
+                         * jacs[d])
         rhs = ([-x for x in div_d] if rhs is None
                else [r - x for r, x in zip(rhs, div_d)])
 
+    if wall is not None:
+        # constant streamwise pressure-gradient forcing
+        f_mom_x = rho * wall.f_x
+        return [rhs[0], rhs[1] + f_mom_x, rhs[2], rhs[3],
+                rhs[4] + f_mom_x * vel[0]]
+
     # Lundgren linear forcing + proportional TKE controller.  Whole meshes
     # sit in the block, so the global quadrature means are computed in-pass.
-    n_elem_total = k**3
+    n_elem_total = geo.k[0] * geo.k[1] * geo.k[2]
     mom = u[1:4]
     mom_fluct = [m - geo.env_mean(m, n_elem_total) for m in mom]
     ke_density = 0.5 * (mom[0] * vel[0] + mom[1] * vel[1] + mom[2] * vel[2])

@@ -90,9 +90,12 @@ def test_wall_bc_conserves_mass():
 
 def test_wall_stress_decelerates_unforced_flow():
     """Without forcing the only x-momentum sink is the modeled wall stress:
-    bulk momentum must decrease, and faster with a larger stress scaling."""
+    bulk momentum must decrease, and faster with a larger stress scaling.
+    The state is drawn at the config's own u_tau (u_tau also scales the
+    reference profile: drawn at 0 it has no bulk flow); only the advance
+    runs with the forcing off."""
+    u0 = channel.sample_initial_state(jax.random.PRNGKey(2), CFG)
     cfg = dataclasses.replace(CFG, u_tau=0.0)  # f_x = 0, walls still on
-    u0 = channel.sample_initial_state(jax.random.PRNGKey(2), cfg)
     mom0 = float(dgsem.quadrature_mean(u0, _weights_dg(cfg))[1])
     assert mom0 > 0.0
     moms = {}

@@ -475,3 +475,26 @@ def test_single_program_trains_bit_identical_to_dispatch(tmp_path):
     for got, want in zip(jax.tree.leaves(results[True]),
                          jax.tree.leaves(results[False])):
         np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("name", ["hit_les_reduced", "channel_wm_reduced"])
+def test_guard_reverts_are_counted_per_scenario(tmp_path, name):
+    """A planted bank row with negative density turns its env non-finite
+    in every RL interval; the guard reverts it each step, and the fleet's
+    metrics record counts those env-steps as `<scenario>/reverted_envs`."""
+    runner = fleet.make_fleet_runner(
+        (name,), total_envs=3,
+        run_cfg=FleetRunnerConfig(n_iterations=1, eval_every=100,
+                                  checkpoint_every=100,
+                                  checkpoint_dir=str(tmp_path),
+                                  async_checkpoint=False, bank_size=4),
+        use_artifacts=False)
+    orch = runner.forch.orchs[name]
+    orch.bank = orch.bank.at[0, ..., 0].multiply(-1.0)
+    # the prologue's rollout (trajectory 0, which update 0 consumes)
+    u0, _ = runner.program.draw_padded_inputs(name, runner._keys(0)[name])
+    planted = int(jnp.sum(jnp.any(u0[..., 0] < 0,
+                                  axis=tuple(range(1, u0.ndim - 1)))))
+    assert 0 < planted < 3
+    (rec,) = runner.train(resume=False)
+    assert rec[f"{name}/reverted_envs"] == planted * orch.env.n_actions

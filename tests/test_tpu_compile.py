@@ -6,8 +6,11 @@ on the chip (layouts it cannot lower, VMEM overruns) — which interpret-mode
 parity tests cannot see.  Sizes are the paper's Table 1 meshes (24 and 32
 DOF: N = 5 and 7, 4^3 elements) at a fleet of 16 environments.  Each test
 asserts the compiled program holds the Mosaic kernel (`tpu_custom_call`).
-The RL interval (`solver.advance_rl_interval` with kernels on) is compiled
-whole, to check that its RK loop runs on the kernel's planar layout.
+The wall-modelled channel's `fused_wall_rhs` is compiled at its Re_tau
+2000 block (N = 5, 8 x 8 x 4 elements: 256 lanes per mesh).  The RL
+interval (`solver.advance_rl_interval` and `channel.advance_rl_interval`
+with kernels on) is compiled whole, to check that its RK loop runs on the
+kernel's planar layout.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -22,11 +25,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.cfd import solver
-from repro.configs import relexi_hit
+from repro.cfd import channel, solver
+from repro.configs import channel_wmles, relexi_hit
 from repro.kernels import policy
 from repro.kernels.dg_derivative import dg_derivative3
-from repro.kernels.rhs import fused_navier_stokes_rhs
+from repro.kernels.rhs import fused_navier_stokes_rhs, fused_wall_rhs_planar
 from repro.kernels.smagorinsky import smagorinsky_nut
 from repro.kernels.wall_model import wall_model_tau
 
@@ -70,6 +73,21 @@ def test_fused_rhs_compiles_for_v5e(one_chip, dof):
         lambda u, cs, d, w: fused_navier_stokes_rhs(u, cs, d, w, **kw),
         one_chip, mesh + (5,), mesh, (n, n), (n,))
     _assert_kernel(compiled)
+
+
+def test_fused_wall_rhs_compiles_for_v5e(one_chip):
+    """The wall-modelled channel's kernel (Re_tau 2000: N = 5 on 8 x 8 x 4
+    elements) at its production block, one 256-lane mesh per grid step."""
+    cfg = channel_wmles.RE2000
+    ops = cfg.operators()
+    n, lanes = cfg.n, N_ENVS * 256
+    kw = dict(k=cfg.n_elem, block_e=1, interpret=False,
+              **channel.wall_rhs_scalars(cfg, ops))
+    compiled = _compile(
+        lambda u, s, d, w: fused_wall_rhs_planar(u, s, d, w, **kw),
+        one_chip, (5, n**3, lanes), (1, lanes), (n, n), (n,))
+    _assert_kernel(compiled)
+    assert "fused_wall_rhs" in compiled.as_text()
 
 
 @pytest.mark.parametrize("dof", sorted(CONFIGS))
@@ -132,20 +150,11 @@ def _reachable(comps: dict, root: str) -> list[str]:
     return out
 
 
-@pytest.mark.parametrize("dof", sorted(CONFIGS))
-def test_rl_interval_steps_planar_carry_on_v5e(one_chip, dof, monkeypatch):
-    """The substep loop of `advance_rl_interval` holds the Mosaic kernel and
-    no array of the natural layout's rank (the 8-D state, its 7-D
+def _assert_planar_rk_loop(compiled):
+    """The substep loop holds the Mosaic kernel once per RK stage and no
+    array of the natural layout's rank (the 8-D state, its 7-D
     coefficients, their transposes): the relayouts stay at the interval's
     boundary, outside the RK loop."""
-    # the kernel as on the chip: compiled, not interpreted (the backend
-    # seen here is the CPU)
-    monkeypatch.setattr(policy, "default_interpret", lambda: False)
-    cfg = dataclasses.replace(CONFIGS[dof], use_kernels=True)
-    n, k = cfg.n_poly + 1, cfg.n_elem
-    compiled = _compile(
-        lambda u, cs: solver.advance_rl_interval(u, cs, cfg), one_chip,
-        (N_ENVS, k, k, k, n, n, n, 5), (N_ENVS, k, k, k))
     comps = _computations(compiled.as_text())
     bodies = [b for lines in comps.values() for line in lines
               if " while(" in line
@@ -156,3 +165,29 @@ def test_rl_interval_steps_planar_carry_on_v5e(one_chip, dof, monkeypatch):
     ranks = {len(dims.split(",")) for line in body
              for dims in re.findall(r"\b[a-z]+[0-9]*\[([0-9,]+)\]", line)}
     assert max(ranks) < 7, sorted(ranks)
+
+
+@pytest.mark.parametrize("dof", sorted(CONFIGS))
+def test_rl_interval_steps_planar_carry_on_v5e(one_chip, dof, monkeypatch):
+    """`solver.advance_rl_interval` steps a planar carry (HIT)."""
+    # the kernel as on the chip: compiled, not interpreted (the backend
+    # seen here is the CPU)
+    monkeypatch.setattr(policy, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(CONFIGS[dof], use_kernels=True)
+    n, k = cfg.n_poly + 1, cfg.n_elem
+    _assert_planar_rk_loop(_compile(
+        lambda u, cs: solver.advance_rl_interval(u, cs, cfg), one_chip,
+        (N_ENVS, k, k, k, n, n, n, 5), (N_ENVS, k, k, k)))
+
+
+def test_channel_rl_interval_steps_planar_carry_on_v5e(one_chip,
+                                                       monkeypatch):
+    """`channel.advance_rl_interval` steps a planar carry through
+    `fused_wall_rhs` (Re_tau 2000 channel, 16 envs)."""
+    monkeypatch.setattr(policy, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(channel_wmles.RE2000, use_kernels=True)
+    n, (kx, ky, kz) = cfg.n, cfg.n_elem
+    _assert_planar_rk_loop(_compile(
+        lambda u, sb, st: channel.advance_rl_interval(u, sb, st, cfg),
+        one_chip, (N_ENVS, kx, ky, kz, n, n, n, 5), (N_ENVS, kx, kz),
+        (N_ENVS, kx, kz)))
